@@ -55,10 +55,6 @@ KNOBS: tuple[Knob, ...] = (
          "kernels.conv2d.forced_tile_w",
          "Force a specific W tile width for the Pallas conv kernel "
          "(0 = let the search/heuristic pick)."),
-    Knob("REPRO_PALLAS_COMPILE", "0", "flag",
-         "kernels.ops.interpret_mode",
-         "1 compiles Pallas kernels for the accelerator; 0 (default) "
-         "runs them in interpret mode, which works on CPU."),
     # -- launch / parallelism ------------------------------------------
     Knob("REPRO_FSDP", "1", "flag",
          "launch.partition.partition_params",

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -69,12 +71,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref,
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, scale: float | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool | None = None) -> jnp.ndarray:
     """q: (BH, Sq, hd); k, v: (BH, Sk, hd) -- pre-broadcast for GQA.
 
     Sq/Sk must be multiples of the block sizes (ops.py pads); hd should be
     a multiple of 128 on real hardware for MXU alignment (any hd works in
-    interpret mode)."""
+    interpret mode).  ``interpret`` None follows the platform."""
     BH, Sq, hd = q.shape
     Sk = k.shape[1]
     assert Sq % block_q == 0 and Sk % block_k == 0
@@ -99,7 +101,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(q, k, v)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _ssd_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, o_ref, h_scr,
                 *, chunk: int):
@@ -62,9 +64,11 @@ def _ssd_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, o_ref, h_scr,
     o_ref[0, :, 0] = y.astype(o_ref.dtype)
 
 
-def mamba2_ssd(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
+def mamba2_ssd(x, dt, A, B, C, *, chunk: int = 64,
+               interpret: bool | None = None):
     """x: (Bb, T, H, hp); dt: (Bb, T, H); A: (H,); B, C: (Bb, T, H, ds).
-    Returns y (Bb, T, H, hp) with h0 = 0.  T must be a chunk multiple."""
+    Returns y (Bb, T, H, hp) with h0 = 0.  T must be a chunk multiple.
+    ``interpret`` None follows the platform."""
     Bb, T, H, hp = x.shape
     ds = B.shape[-1]
     assert T % chunk == 0
@@ -80,7 +84,7 @@ def mamba2_ssd(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
         out_specs=sx,
         out_shape=jax.ShapeDtypeStruct((Bb, T, H, hp), x.dtype),
         scratch_shapes=[pltpu.VMEM((hp, ds), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(x, dt, la, B, C)

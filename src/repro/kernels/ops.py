@@ -1,22 +1,21 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles padding to block multiples, GQA head broadcasting, and the
-CPU-vs-TPU switch: ``interpret=True`` (the default) executes the kernel
-bodies in Python on CPU for validation; on a real TPU runtime set
-REPRO_PALLAS_COMPILE=1 to compile via Mosaic.  The env var is resolved at
+CPU-vs-TPU switch: ``interpret_mode()`` (interpret exactly when the
+platform is the CPU, compile with Mosaic everywhere else) is read at
 *call* time (mirroring ``models/cnn.py::conv_backend``) and threaded into
-the jit'd inner functions as a static argument, so flipping it after import
--- or between calls -- retraces instead of silently reusing the old mode.
+the jit'd inner functions as a static argument, so a trace made for one
+platform is never reused for another.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.dtype_policy import conv_dtype, policy_jnp_dtype
+from repro.kernels import interpret_mode
 from repro.kernels import conv2d as _conv
 from repro.kernels import flash_attention as _fa
 from repro.kernels import mamba2_ssd as _ssd
@@ -25,13 +24,6 @@ from repro.kernels import rwkv6_wkv as _wkv
 # re-exported here so callers reach every kernel through one surface.
 from repro.kernels.quant import (boundary_roundtrip,  # noqa: F401
                                  dequantize_boundary, quantize_boundary)
-
-
-def interpret_mode() -> bool:
-    """Resolve the Pallas execution mode from the environment *now*.
-
-    True (default) = interpret on CPU; REPRO_PALLAS_COMPILE=1 = Mosaic."""
-    return os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
 
 def _pad_to(x, axis, mult):

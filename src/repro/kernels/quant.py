@@ -35,16 +35,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.dtype_policy import policy_jnp_dtype
+from repro.kernels import interpret_mode
 
 # Per-tile VMEM budget for the quantize kernel (fp32 in + int8 out + scales).
 _VMEM_BUDGET = 8 * 1024 * 1024
 _LANE = 128
-
-
-def _interpret_mode() -> bool:
-    """Mirrors ``ops.interpret_mode`` (ops imports this module, not vice
-    versa, so the env read is duplicated rather than creating a cycle)."""
-    return os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
 
 def _use_pallas(backend: str | None = None) -> bool:
@@ -224,7 +219,7 @@ def quantize_boundary(x, axis: int | None = None, *,
     if axis is None:
         axis = default_channel_axis(x.ndim)
     return _quantize(x, axis=axis, use_pallas=_use_pallas(backend),
-                     interpret=_interpret_mode())
+                     interpret=interpret_mode())
 
 
 def dequantize_boundary(values, scales, axis: int | None = None, *,
@@ -234,7 +229,7 @@ def dequantize_boundary(values, scales, axis: int | None = None, *,
         axis = default_channel_axis(values.ndim)
     return _dequantize(values, scales, axis=axis,
                        use_pallas=_use_pallas(backend),
-                       interpret=_interpret_mode(),
+                       interpret=interpret_mode(),
                        out_dtype=out_dtype or jnp.float32)
 
 

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr,
                 *, block_t: int):
@@ -42,10 +44,11 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr,
 
 
 def rwkv6_wkv(r, k, v, w, u, *, block_t: int = 64,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """r,k,v,w: (B, T, H, hd); u: (H, hd). Returns out (B, T, H, hd).
 
-    T must be a multiple of block_t (ops.py pads)."""
+    T must be a multiple of block_t (ops.py pads).  ``interpret`` None
+    follows the platform."""
     B, T, H, hd = r.shape
     assert T % block_t == 0
     nt = T // block_t
@@ -59,7 +62,7 @@ def rwkv6_wkv(r, k, v, w, u, *, block_t: int = 64,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, T, H, hd), r.dtype),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(r, k, v, w, u)

@@ -30,7 +30,7 @@ from repro.analysis.hlo import (collective_bytes, collective_counts,
 from repro.configs import INPUT_SHAPES, all_configs, shape_skips
 from repro.configs.base import InputShape, ModelConfig
 from repro.launch import partition as PT
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_axes, make_production_mesh
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "benchmarks", "out", "dryrun")
@@ -53,6 +53,7 @@ def _measure(cfg: ModelConfig, shape: InputShape, mesh, *,
     """Lower + compile one variant; return scalar cost terms + artefacts."""
     from repro.models import layers as Lmod
     from repro.models import moe_ep
+    mesh = auto_axes(mesh)
     Lmod.SCAN_UNROLL = scan_unroll
     Lmod.HINT_AXIS = "model"      # TP sharding hints (§Perf P3)
     Lmod.HINT_MESH = mesh

@@ -21,6 +21,17 @@ def make_debug_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")):
     return jax.make_mesh(shape, axes)
 
 
+def auto_axes(mesh):
+    """The same devices and axis names with every axis ``Auto``: shardings
+    propagate through the partitioner, as the sharding hints in
+    ``models/layers.py`` assume, instead of being typed per op (the
+    ``jax.make_mesh`` default, under which gathers and sharded
+    contractions need an explicit ``out_sharding``)."""
+    return jax.sharding.Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
+
+
 def data_axes(mesh) -> tuple[str, ...]:
     """Axes the batch dimension shards over (pod joins data-parallel in the
     baseline multi-pod configuration)."""

@@ -13,7 +13,14 @@ env knobs (or ``--drop``), and reports recoveries -- retries, stage
 merges, Pareto-front re-picks -- next to throughput.  ``--tier-faults
 {crash,straggler,shed}`` layers a canned compute-side chaos profile on
 the first server tier (over any ``REPRO_TIER_*`` / ``REPRO_TIER{k}_*``
-env config), exercising circuit breakers and standby-tier failover."""
+env config), exercising circuit breakers and standby-tier failover.
+
+``main(argv)`` turns on the persistent compilation cache
+(``launch/compile_cache.py``) and, for ``--cnn``, returns what it served
+(inputs, logits, stats) so in-process callers can check it.  Times from
+the virtual clock (link and tier models) are printed as *modelled*; wall
+times are host-clock seconds around work that ends in
+``block_until_ready``."""
 from __future__ import annotations
 
 import argparse
@@ -28,6 +35,7 @@ from repro.configs import all_configs
 from repro.core import CONV_DTYPES, TPU_EDGE_CLOUD, WIRE_DTYPES, smartsplit
 from repro.core.dtype_policy import conv_dtype
 from repro.core.dtype_policy import dtype_bytes as policy_bytes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.partition import split_boundary_struct
 from repro.models import transformer as T
 from repro.models.profiles import transformer_profile
@@ -66,7 +74,7 @@ def _tier_fault_models(profile, hw, clock):
     return tiers
 
 
-def serve_cnn_stream(args) -> None:
+def serve_cnn_stream(args) -> dict:
     """``--cnn --concurrency N``: a stream of N single-sample requests
     through the batched split-serving engine (``serving.cnn_engine``):
     bounded queue, (model, resolution, dtype, wire) batch buckets,
@@ -96,21 +104,25 @@ def serve_cnn_stream(args) -> None:
         wire=args.wire_dtype, links=links, tier_faults=tier_models,
         policy=RetryPolicy.from_env())
     rng = np.random.default_rng(0)
-    for i in range(args.concurrency):
-        x = rng.normal(size=cnn_lib.INPUT_SHAPE).astype(np.float32)
-        eng.submit(x, args.cnn, at=0.0)
+    xs = rng.normal(size=(args.concurrency,) + cnn_lib.INPUT_SHAPE) \
+        .astype(np.float32)
+    reqs = [eng.submit(x, args.cnn, at=0.0) for x in xs]
     t0 = time.perf_counter()
     eng.run_until_idle()
+    # row i answers xs[i]; None when any request was shed or failed
+    logits = jnp.stack([r.logits for r in reqs]) \
+        if all(r.logits is not None for r in reqs) else None
+    jax.block_until_ready(logits)
     dt = time.perf_counter() - t0
     s = eng.stats()
     mode = "pipelined" if s["pipelined"] else "sequential"
     print(f"served {s['served']}/{s['submitted']} requests "
           f"({mode}, {s['batches']} batches of "
-          f"~{s['avg_batch_size']:.1f}) in {dt:.1f}s wall / "
-          f"{s['virtual_span_s']:.4f}s virtual "
-          f"({s['requests_per_s']:.1f} req/s virtual; "
+          f"~{s['avg_batch_size']:.1f}) in {dt:.3f}s wall, compiles "
+          f"included / {s['virtual_span_s']:.4f}s modelled "
+          f"({s['requests_per_s']:.1f} req/s modelled; "
           f"p50={s['latency_p50_s'] * 1e3:.1f}ms "
-          f"p99={s['latency_p99_s'] * 1e3:.1f}ms) "
+          f"p99={s['latency_p99_s'] * 1e3:.1f}ms modelled) "
           f"repicks={s['repicks']} merges={s['merges']}")
     if tier_models is not None:
         for k, (ft, br) in enumerate(zip(s["tiers"], s["breakers"])):
@@ -129,9 +141,10 @@ def serve_cnn_stream(args) -> None:
               f"degradation={h['degradation']:.2f} "
               f"({link_c['dropped']} dropped / {link_c['timeouts']} "
               f"timeouts)")
+    return {"params": params, "x": xs, "logits": logits, "stats": s}
 
 
-def serve_cnn(args) -> None:
+def serve_cnn(args) -> dict:
     """Fault-tolerant CNN chain serving (the paper's actual workload).
 
     Plans a K-tier chain placement (``--tiers``; K=2 is the paper's
@@ -163,7 +176,7 @@ def serve_cnn(args) -> None:
     wires = plan.wire_dtypes or ("?",) * len(hw.links)
     print(f"SmartSplit chain: {chain}")
     print(f"  cuts={list(plan.cuts)}/{prof.num_layers} M={microbatch} "
-          f"latency={lat:.2e}s energy={en:.2e}J "
+          f"modelled latency={lat:.2e}s energy={en:.2e}J "
           f"device-mem={mem / 2**20:.1f}MiB ({policy}, "
           f"wire={'/'.join(wires)})")
 
@@ -173,22 +186,27 @@ def serve_cnn(args) -> None:
             link.faults = FaultSpec(drop_rate=args.drop)
     tier_models = _tier_fault_models(args.tier_faults, hw,
                                      links[0]._clock if links else None)
-    rt = ChainRuntime(args.cnn, cnn_lib.init_cnn(
-        jax.random.PRNGKey(0), cnn_lib.CNN_MODELS[args.cnn]),
-        plan, prof, hw, links=links, dtype=policy,
-        wire=args.wire_dtype, microbatches=microbatch,
-        tier_faults=tier_models, policy=RetryPolicy.from_env())
+    params = cnn_lib.init_cnn(jax.random.PRNGKey(0),
+                              cnn_lib.CNN_MODELS[args.cnn])
+    rt = ChainRuntime(args.cnn, params, plan, prof, hw, links=links,
+                      dtype=policy, wire=args.wire_dtype,
+                      microbatches=microbatch, tier_faults=tier_models,
+                      policy=RetryPolicy.from_env())
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(args.batch,) + cnn_lib.INPUT_SHAPE),
                     jnp.float32)
-    t0 = time.perf_counter()
+    walls = []
     for _ in range(args.requests):
+        t0 = time.perf_counter()
         r = rt.infer(x)
         jax.block_until_ready(r.logits)
-    dt = time.perf_counter() - t0
+        walls.append(time.perf_counter() - t0)
     s = rt.stats()
-    print(f"served {s['requests']} requests in {dt:.1f}s "
-          f"({s['requests'] / dt:.2f} req/s); recovered={s['recovered']} "
+    warm = f", then {np.median(walls[1:]):.4f}s median" \
+        if len(walls) > 1 else ""
+    print(f"served {s['requests']} requests of batch {args.batch} in "
+          f"{sum(walls):.3f}s wall: first {walls[0]:.3f}s (compiles "
+          f"included){warm} per request; recovered={s['recovered']} "
           f"merges={s['merges']} repicks={s['repicks']} "
           f"proactive={s['proactive_resplits']} "
           f"active_cuts={s['active_cuts']}")
@@ -210,9 +228,10 @@ def serve_cnn(args) -> None:
               f"degradation={h['degradation']:.2f} "
               f"({link_c['dropped']} dropped / {link_c['timeouts']} "
               f"timeouts / {link_c['outage_hits']} outage-hits)")
+    return {"params": params, "x": x, "logits": r.logits, "stats": s}
 
 
-def main():
+def main(argv: list[str] | None = None) -> dict | None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b",
                     choices=sorted(all_configs()))
@@ -258,14 +277,13 @@ def main():
                          "(int8 = quantized streaming; default: "
                          "REPRO_LINK{k}_WIRE_DTYPE / REPRO_WIRE_DTYPE, "
                          "else follow = the storage dtype)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.cnn:
         if args.concurrency:
-            serve_cnn_stream(args)
-        else:
-            serve_cnn(args)
-        return
+            return serve_cnn_stream(args)
+        return serve_cnn(args)
 
     cfg = all_configs()[args.arch].reduced()
     cfg = dataclasses.replace(cfg, vocab_size=min(cfg.vocab_size, 512))

@@ -92,7 +92,7 @@ def test_vmem_estimate_monotone_and_floored(geom, th, tw):
     cout, _, K, _ = w_shape
     w_in = W + 2 * pad
     w_out = (w_in - K) // stride + 1
-    kw = dict(cin_block=cin, block_co=cout, w_in=w_in, w_out=w_out, K=K,
+    kw = dict(cin_block=cin, block_co=cout, w_out=w_out, K=K,
               stride=stride, cin_per_group=cin, pool_k=pk,
               pool_s=ps or 1)
     est = conv_vmem_bytes(tile_h=th, tile_w=tw, **kw)

@@ -80,12 +80,14 @@ def test_flash_attention_gqa_wrapper_matches_layer_attention():
                                   # the expensive unrolled part)
     (2, 16, 16, 16, 1, 1, 0),     # pointwise
     (1, 4, 8, 20, 3, 2, 1),       # strided
+    (1, 8, 256, 8, 3, 1, 1),      # two 128-lane output-channel blocks
 ])
 def test_conv2d_sweep(n, cin, cout, hw, k, stride, pad, dtype):
     x = (jax.random.normal(KEY, (n, cin, hw, hw)) * 0.5).astype(dtype)
     w = (jax.random.normal(jax.random.fold_in(KEY, 1), (cout, cin, k, k))
          * 0.2).astype(dtype)
-    out = conv2d(x, w, stride=stride, pad=pad, block_co=min(cout, 16))
+    # channel blocks are the whole extent or multiples of 128 lanes
+    out = conv2d(x, w, stride=stride, pad=pad, block_co=min(cout, 128))
     want = ref.conv2d_ref(x, w, stride=stride, pad=pad)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -105,13 +107,14 @@ def test_conv2d_matches_cnn_layer():
 
 
 # ---------------------------------------------------------------------------
-# Pallas execution-mode env: resolved at call time, not import time
+# Pallas execution mode: follows the platform, resolved at call time
 # ---------------------------------------------------------------------------
 def test_pallas_compile_env_resolved_at_call_time(monkeypatch):
-    """Setting REPRO_PALLAS_COMPILE *after* import must change the mode the
-    next kernel call requests (the old module-constant INTERPRET froze the
-    value at import).  The spy forces interpret execution so the test runs
-    on CPU while still observing what the wrapper asked for."""
+    """Kernels interpret exactly when the platform is the CPU and compile
+    on any other, and the platform is read at *call* time, so the next
+    kernel call after a platform change requests the other mode.  The spy
+    forces interpret execution so the test runs on CPU while still
+    observing what the wrapper asked for."""
     requested = []
     real = ops._conv.conv2d
 
@@ -126,11 +129,13 @@ def test_pallas_compile_env_resolved_at_call_time(monkeypatch):
     x = jax.random.normal(KEY, (1, 5, 9, 9)) * 0.3
     w = jax.random.normal(jax.random.fold_in(KEY, 1), (7, 5, 3, 3)) * 0.2
 
-    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
+    assert jax.default_backend() == "cpu"
     assert ops.interpret_mode() is True
     ops.conv2d(x, w, stride=1, pad=1)
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    assert ops.interpret_mode() is False
+    for platform in ("tpu", "gpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert ops.interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     ops.conv2d(x, w, stride=1, pad=1)   # same shapes: must still retrace
     # interpret is a static jit arg, so the compile-mode call cannot have
     # silently reused the interpret-mode executable

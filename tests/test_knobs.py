@@ -89,6 +89,28 @@ def test_intra_repo_markdown_links_resolve():
     assert not broken, "\n".join(broken)
 
 
+def test_compile_cache_placed_from_outside(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code;
+    without it the cache is the fixed, gitignored ``<checkout>/.jax_cache``
+    (a per-process or temporary path would never be hit again)."""
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(REPO / ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert ".jax_cache/" in (REPO / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_docs_reference_real_modules():
     """Module paths cited in the hand-written docs exist (cheap rot
     guard for the architecture pages)."""
