@@ -6,12 +6,20 @@ recorded as an ``Event`` stamped with the link's virtual clock, so tests
 can assert "no silent wrong answer" (a faulty run either matches the
 fault-free logits bit-exactly or carries the recovery that explains why)
 and the chaos harness can aggregate counts/bytes without parsing stdout.
+
+The same module names the runtime's profiler spans (``SPANS``).  The two
+do not mix: events are audit records on the virtual clock, kept in the
+``EventLog``; spans are wall-clock ``jax.profiler.TraceAnnotation``s,
+kept only in the profiler's own trace, beside the device's operations.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import Counter
 from typing import Any
+
+import jax
 
 # Canonical event kinds (the log accepts any string; these are the ones
 # the runtime emits -- tests and the chaos harness key on them).
@@ -38,6 +46,24 @@ BREAKER_OPEN = "breaker_open"        # consecutive tier failures tripped it
 BREAKER_HALF_OPEN = "breaker_half_open"  # cooldown elapsed; probe admitted
 BREAKER_CLOSE = "breaker_close"      # probe succeeded; tier back in rotation
 TIER_FAILOVER = "tier_failover"      # re-picked onto a standby-tier chain
+
+# Profiler spans, one fixed name each (a name never carries a request id).
+# They cost about a microsecond each when no profiler is running.
+SPAN_ENGINE_STEP = "engine.step"     # CnnServingEngine.step: one batch
+SPAN_CHAIN_INFER = "chain.infer"     # ChainRuntime.infer: one batch's chain
+SPAN_CHAIN_STAGE = "chain.stage"     # one stage's eager layer walk
+SPAN_WIRE_SYNC = "wire.sync"         # encode waits for the device work it copies
+SPAN_WIRE_ENCODE = "wire.encode"     # boundary to wire bytes (holds wire.sync)
+SPAN_WIRE_SEND = "wire.send"         # crc32 framing, fault draws, virtual link
+SPAN_WIRE_DECODE = "wire.decode"     # wire bytes back to a device array
+SPANS = (SPAN_ENGINE_STEP, SPAN_CHAIN_INFER, SPAN_CHAIN_STAGE,
+         SPAN_WIRE_SYNC, SPAN_WIRE_ENCODE, SPAN_WIRE_SEND, SPAN_WIRE_DECODE)
+
+
+def spanned(name: str):
+    """Decorator: every call of the function is the profiler span
+    ``name``, early returns and raises included."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
 
 
 @dataclasses.dataclass(frozen=True)

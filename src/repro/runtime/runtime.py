@@ -725,6 +725,7 @@ class ChainRuntime:
         self.hop_merges = [0] * n_hops
 
     # -- stages --------------------------------------------------------
+    @ev.spanned(ev.SPAN_CHAIN_STAGE)
     def _run(self, x, start: int, stop: int):
         return cnn_lib.apply_cnn(self.layers, self.params, x, start=start,
                                  stop=stop, backend=self.backend,
@@ -836,6 +837,7 @@ class ChainRuntime:
                     self.n_proactive += 1
 
     # -- the request loop ----------------------------------------------
+    @ev.spanned(ev.SPAN_CHAIN_INFER)
     def infer(self, x, *, at: float | None = None) -> ChainInferenceResult:
         """Run one request through the chain (or raise
         SplitUnrecoverable).

@@ -190,6 +190,7 @@ _FAIL_KINDS = {LinkDropped: ev.DROP, LinkTimeout: ev.TIMEOUT,
                LinkOutage: ev.OUTAGE, ChecksumError: ev.CHECKSUM_FAIL}
 
 
+@ev.spanned(ev.SPAN_WIRE_SEND)
 def send_with_retry(link: FaultyLink, payload: bytes,
                     policy: RetryPolicy = RetryPolicy(), *,
                     rng: np.random.Generator | None = None,

@@ -17,12 +17,14 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dtype_policy import policy_jnp_dtype
 from repro.kernels.quant import (default_channel_axis, dequantize_boundary,
                                  quantize_boundary)
+from repro.runtime import events as ev
 from repro.runtime.transfer import pack_frames, unpack_frames
 
 # Part labels for framed int8 payloads -- the chaos harness keys on these
@@ -46,6 +48,18 @@ class BoundaryMeta:
     raw_bytes: int = 0           # storage-dtype serialized size (stats)
 
 
+def _sync(*arrays) -> None:
+    """Start each array's copy to the host, then wait for the device work
+    that makes them inside the span ``wire.sync``: a trace then tells the
+    stage's device time apart from the codec's host time.  The copies are
+    queued first so that the wait adds no round trip to the hop."""
+    for a in arrays:
+        a.copy_to_host_async()
+    with jax.profiler.TraceAnnotation(ev.SPAN_WIRE_SYNC):
+        jax.block_until_ready(arrays)
+
+
+@ev.spanned(ev.SPAN_WIRE_ENCODE)
 def encode_boundary(arr, wire: str, *, backend: str | None = None
                     ) -> tuple[bytes, BoundaryMeta]:
     """Encode ``arr`` for the wire; returns ``(payload, meta)``.
@@ -60,6 +74,7 @@ def encode_boundary(arr, wire: str, *, backend: str | None = None
     if wire == "int8":
         axis = default_channel_axis(arr.ndim)
         q, scales = quantize_boundary(arr, axis, backend=backend)
+        _sync(q, scales)
         q_host = np.ascontiguousarray(np.asarray(q))
         s_host = np.ascontiguousarray(np.asarray(scales, dtype=np.float32))
         payload = pack_frames(s_host.tobytes(), q_host.tobytes())
@@ -68,11 +83,13 @@ def encode_boundary(arr, wire: str, *, backend: str | None = None
             framed=INT8_FRAME_LABELS, raw_bytes=raw_bytes)
     jdt = policy_jnp_dtype(wire)
     sent = arr if arr.dtype == jdt else arr.astype(jdt)
+    _sync(sent)
     host = np.ascontiguousarray(np.asarray(sent))
     return host.tobytes(), BoundaryMeta(
         wire=wire, storage=storage, shape=shape, raw_bytes=raw_bytes)
 
 
+@ev.spanned(ev.SPAN_WIRE_DECODE)
 def decode_boundary(payload: bytes, meta: BoundaryMeta, *,
                     backend: str | None = None) -> jnp.ndarray:
     """Invert ``encode_boundary`` back to a device array in the storage

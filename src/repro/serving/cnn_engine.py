@@ -370,6 +370,7 @@ class CnnServingEngine:
         self.log.emit(ev.DEADLINE_EXPIRED, t, rid=req.rid, phase=phase,
                       arrival_s=req.arrival_s, deadline_s=req.deadline_s)
 
+    @ev.spanned(ev.SPAN_ENGINE_STEP)
     def step(self) -> bool:
         """Dispatch one batch (FIFO across buckets by head arrival).
         Returns False when nothing is pending."""
