@@ -133,7 +133,7 @@ def phase_pallas(in_shape=None, models=MODELS) -> None:
                 warm = min(walls[1:])
                 print(f"  {model} pallas {dtype}: first call {walls[0]:.3f}s"
                       f" wall (~{walls[0] - warm:.3f}s compiling), then "
-                      f"{warm:.4f}s per request (batch 1, eager layer walk)",
+                      f"{warm:.4f}s per request (batch 1, compiled walk)",
                       flush=True)
                 _check(f"{model} pallas {dtype} vs xla fp32", got, want,
                        PALLAS_TOL[dtype])

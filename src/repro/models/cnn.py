@@ -14,6 +14,7 @@ the split-execution tests and the serving example."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 from typing import Any
@@ -488,43 +489,27 @@ def init_cnn(key, layers: list[Layer], in_shape: tuple = INPUT_SHAPE):
     return params
 
 
-def apply_cnn(layers: list[Layer], params, x, *, start: int = 0,
-              stop: int | None = None, backend: str | None = None,
-              dtype: str | None = None):
-    """Run layers [start, stop) -- the split runtime building block.
+# One compiled program per stage: (layer range, backend, dtype, conv knobs)
+# -> jitted walk; jit adds the input's shape and dtype.  Module-level like
+# ``core.smartsplit``'s plan cache, so every runtime, engine and reference
+# caller that walks the same range shares the program.
+_WALKS: dict[tuple, Any] = {}
+_WALK_TRACES = 0
+_WALK_HITS = 0
 
-    On the pallas backend the walk peeks up to two layers ahead: a conv
-    paper-layer immediately followed by relu/relu6 collapses into a single
-    fused kernel launch (conv + bias + activation in the epilogue), and if
-    a maxpool follows the activation the whole conv->relu->maxpool *triple*
-    becomes one launch with the pool running on the fp32 accumulator (no
-    intermediate activation ever written to HBM).  All layers are still
-    *counted* -- split indices keep paper-layer semantics -- and fusion
-    only happens when every member sits wholly on one side of the split
-    ([start, stop)), so the boundary payload is bit-identical to the
-    unfused walk.
 
-    ``dtype`` is the storage policy (``conv_dtype``; env
-    ``REPRO_CONV_DTYPE``): under ``bf16`` every conv stores its weights /
-    activations / pooled outputs in bfloat16 (fp32 accumulate), so the
-    activation stream -- including any split-boundary payload -- flows at
-    half the bytes.  Linear/gap_linear heads follow the same rule (bf16
-    weight/activation storage, fp32 matmul), so the analytic profile's
-    per-layer weight and activation bytes match the runtime everywhere."""
-    stop = len(layers) if stop is None else stop
-    if not 0 <= start <= stop <= len(layers):
-        raise ValueError(
-            f"apply_cnn: need 0 <= start <= stop <= {len(layers)} "
-            f"(L), got start={start}, stop={stop}")
-    bk = conv_backend(backend)
-    dt = conv_dtype(dtype)
+def _walk(layers: tuple, bk: str, dt: str, params, x):
+    """The traced body of ``apply_cnn``: layers (already the range) in
+    order, with the pallas fusion peek inside the range only."""
+    global _WALK_TRACES
+    _WALK_TRACES += 1               # Python runs only while jit traces
     if dt != "fp32":
         # the storage invariant starts at the input: even a degenerate
         # l1=0 split (COC) uploads the policy-dtype tensor the profile's
         # input_bytes term charges
         jdt = policy_jnp_dtype(dt)
         x = x if x.dtype == jdt else x.astype(jdt)
-    i = start
+    i, stop = 0, len(layers)
     while i < stop:
         layer = layers[i]
         if (bk == "pallas" and layer.kind == "conv" and i + 1 < stop
@@ -545,6 +530,96 @@ def apply_cnn(layers: list[Layer], params, x, *, start: int = 0,
         x = apply_layer(layer, params[i], x, backend=bk, dtype=dt)
         i += 1
     return x
+
+
+def _walk_key(layers: list[Layer], start: int, stop: int,
+              backend: str | None, dtype: str | None) -> tuple:
+    """The cache key of one stage's program.  The conv knobs are resolved
+    here because inside the program ``kernels/ops.py::conv2d`` reads them
+    once, at trace time: a flipped env var must select another program."""
+    from repro.kernels import interpret_mode
+    from repro.kernels.conv2d import search_enabled, tile_w_override
+    return (tuple(layers[start:stop]), start, stop, conv_backend(backend),
+            conv_dtype(dtype), interpret_mode(), tile_w_override(0),
+            search_enabled(None))
+
+
+def _compiled_walk(key: tuple):
+    fn = _WALKS.get(key)
+    if fn is None:
+        layers, _, _, bk, dt = key[:5]
+        # no excess precision: every op's result is rounded to its dtype,
+        # so the bf16 storage policy holds inside a program as at its ends
+        fn = _WALKS[key] = jax.jit(
+            functools.partial(_walk, layers, bk, dt),
+            compiler_options={"xla_allow_excess_precision": False})
+    return fn
+
+
+def walk_cache_stats() -> dict[str, int]:
+    """Stage programs traced (new range, knobs, shape or dtype) and calls
+    served by an already traced program, since the process started."""
+    return {"traces": _WALK_TRACES, "hits": _WALK_HITS}
+
+
+def apply_cnn(layers: list[Layer], params, x, *, start: int = 0,
+              stop: int | None = None, backend: str | None = None,
+              dtype: str | None = None):
+    """Run layers [start, stop) -- the split runtime building block.
+
+    The range runs as one compiled program, cached per (layers of the
+    range, start, stop, resolved backend and dtype, and the conv knobs
+    ``kernels/ops.py::conv2d`` resolves: ``interpret_mode()``,
+    ``REPRO_CONV_TILE_W``, ``REPRO_CONV_SEARCH``); jit adds the input's
+    shape and dtype.  The knobs are in the key because the program reads
+    them once, when it is traced, so flipping one selects another
+    program instead of reusing a stale grid.  Only ``params[start:stop]``
+    is passed, as an argument: no weight is baked into a program.  A range
+    first seen -- a stage merge, re-pick, failover or on-device fallback,
+    or the non-pipelined engine's batch sizes 1..``max_batch`` -- traces
+    and compiles once, which costs wall-clock time only, never virtual
+    time.  ``walk_cache_stats`` counts traces and hits.  Called inside a
+    caller's trace (jit, grad, vmap), the walk is traced into the caller's
+    program instead, under the caller's compiler options.
+
+    On the pallas backend the walk peeks up to two layers ahead: a conv
+    paper-layer immediately followed by relu/relu6 collapses into a single
+    fused kernel launch (conv + bias + activation in the epilogue), and if
+    a maxpool follows the activation the whole conv->relu->maxpool *triple*
+    becomes one launch with the pool running on the fp32 accumulator (no
+    intermediate activation ever written to HBM).  All layers are still
+    *counted* -- split indices keep paper-layer semantics -- and fusion
+    only happens when every member sits wholly on one side of the split
+    ([start, stop)), so the boundary payload is bit-identical to the
+    unfused walk.
+
+    ``dtype`` is the storage policy (``conv_dtype``; env
+    ``REPRO_CONV_DTYPE``): under ``bf16`` every conv stores its weights /
+    activations / pooled outputs in bfloat16 (fp32 accumulate), so the
+    activation stream -- including any split-boundary payload -- flows at
+    half the bytes.  Linear/gap_linear heads follow the same rule (bf16
+    weight/activation storage, fp32 matmul), so the analytic profile's
+    per-layer weight and activation bytes match the runtime everywhere."""
+    global _WALK_HITS
+    stop = len(layers) if stop is None else stop
+    if not 0 <= start <= stop <= len(layers):
+        raise ValueError(
+            f"apply_cnn: need 0 <= start <= stop <= {len(layers)} "
+            f"(L), got start={start}, stop={stop}")
+    stage_params = params[start:stop]
+    if any(isinstance(leaf, jax.core.Tracer)
+           for leaf in jax.tree.leaves((stage_params, x))):
+        # under a caller's jit, grad or vmap the walk joins the caller's
+        # program, as a nested jit would: only a top-level jit takes
+        # compiler options
+        return _walk(tuple(layers[start:stop]), conv_backend(backend),
+                     conv_dtype(dtype), stage_params, x)
+    fn = _compiled_walk(_walk_key(layers, start, stop, backend, dtype))
+    traces = _WALK_TRACES
+    y = fn(stage_params, x)
+    if _WALK_TRACES == traces:
+        _WALK_HITS += 1
+    return y
 
 
 def apply_split(layers: list[Layer], params, x, split_index: int,

@@ -51,7 +51,9 @@ TIER_FAILOVER = "tier_failover"      # re-picked onto a standby-tier chain
 # They cost about a microsecond each when no profiler is running.
 SPAN_ENGINE_STEP = "engine.step"     # CnnServingEngine.step: one batch
 SPAN_CHAIN_INFER = "chain.infer"     # ChainRuntime.infer: one batch's chain
-SPAN_CHAIN_STAGE = "chain.stage"     # one stage's eager layer walk
+SPAN_CHAIN_STAGE = "chain.stage"     # one stage's compiled walk (dispatch;
+                                     # device time shows in wire.sync /
+                                     # the logits wait)
 SPAN_WIRE_SYNC = "wire.sync"         # encode waits for the device work it copies
 SPAN_WIRE_ENCODE = "wire.encode"     # boundary to wire bytes (holds wire.sync)
 SPAN_WIRE_SEND = "wire.send"         # crc32 framing, fault draws, virtual link

@@ -469,6 +469,7 @@ class CnnServingEngine:
             })
         lat = np.asarray(self._latencies) if self._latencies else \
             np.zeros(1)
+        walk = cnn_lib.walk_cache_stats()
         return {
             "submitted": self.n_submitted,
             "queued": self.n_pending,
@@ -496,6 +497,9 @@ class CnnServingEngine:
             "failovers": sum(rt.n_failovers for rt in runtimes),
             "fallback_device": sum(rt.n_fallback_device
                                    for rt in runtimes),
+            # stage programs (``cnn.walk_cache_stats``, process-wide)
+            "walk_traces": walk["traces"],
+            "walk_hits": walk["hits"],
             "tiers": None if self.tier_faults is None else
                 [ft.counters() for ft in self.tier_faults],
             "breakers": None if self.breakers is None else

@@ -180,6 +180,9 @@ def _spy_counts(monkeypatch):
 
     monkeypatch.setattr(ops, "conv2d", conv_spy)
     monkeypatch.setattr(jax.lax, "reduce_window", rw_spy)
+    # the spies see the walk only while it is traced: start from no stage
+    # program, and keep the spied ones out of the shared cache
+    monkeypatch.setattr(cnn, "_WALKS", {})
     return counts
 
 
@@ -256,6 +259,8 @@ def test_full_model_walk_fuses_every_triple(model, monkeypatch):
 
     rw_calls = []
     real_rw = jax.lax.reduce_window
+    # trace anew, and keep the stubbed program out of the shared cache
+    monkeypatch.setattr(cnn, "_WALKS", {})
     monkeypatch.setattr(cnn, "_conv2d", fake_conv2d)
     monkeypatch.setattr(jax.lax, "reduce_window",
                         lambda *a, **kw: (rw_calls.append(1),

@@ -111,3 +111,35 @@ def test_int8_codec_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in _compiled_text(
         dq, _sds(values.shape, values.dtype, one_chip),
         _sds(scales.shape, scales.dtype, one_chip))
+
+
+def test_stage_program_keeps_conv_names_for_v5e(one_chip, monkeypatch):
+    """A compiled stage of ``apply_cnn`` keeps every Mosaic conv's
+    instruction name ``_conv2d.<n>`` (the jitted wrapper's), which the
+    benchmark's ``conv_roofline`` reader matches: MobileNetV2's last seven
+    layers at 224 px, thirteen conv launches in one program."""
+    import re
+
+    from repro.models import cnn
+    # compile the kernels rather than interpret them, and keep this
+    # program out of the shared stage cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(cnn, "_WALKS", {})
+    layers, start = cnn.MOBILENET_V2, 14
+    key = cnn._walk_key(layers, start, len(layers), "pallas", "fp32")
+    assert key[5] is False                  # interpret_mode()
+    params = jax.eval_shape(
+        lambda: cnn.init_cnn(jax.random.PRNGKey(0), layers))[start:]
+    x = _sds((1,) + cnn.shapes_through(layers)[start - 1], jnp.float32,
+             one_chip)
+    with jax.default_matmul_precision("highest"):
+        text = cnn._compiled_walk(key).lower(
+            jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), params),
+            x).compile().as_text()
+    names = re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    launches = sum(1 if l.kind == "conv" else 3 if l.expand != 1 else 2
+                   for l in layers[start:] if l.kind in ("conv", "invres"))
+    assert launches == 13
+    assert len(names) == launches
+    assert all(re.fullmatch(r"_conv2d\.\d+", n) for n in names), names
