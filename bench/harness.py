@@ -491,6 +491,9 @@ def run_cell(cell: spec_lib.Cell, seed: int, seconds: float, trace: bool,
         # the engine's own counters over the window and its drain
         "engine_window": {k: after[k] - before[k]
                           for k in ("served", "batches")},
+        # the engine's whole stats() at the window's start and after its
+        # drain, for a reader of any other counter
+        "engine_stats": {"before": before, "after": after},
     }
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):

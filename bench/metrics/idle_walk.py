@@ -1,0 +1,11 @@
+"""Share of the traced window, in %, in which the first chip ran nothing
+while the innermost program span on the host was the compiled layer walk's
+``chain.stage``: ``spans.idle_share`` of layer ``walk``."""
+from bench import spans
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    if trace is None:
+        return None
+    return spans.idle_share(trace, spans.program_spans(trace), "walk")

@@ -3,11 +3,9 @@
 Straightforward ``jax.numpy``/``lax`` in float32 over the configuration
 file's layer list, with no kernel, batching or cache, and nothing imported
 from the program.  It follows the program's layer semantics
-(``models/cnn.py``): a ``conv`` layer is convolution plus bias; relu,
-relu6, maxpool and dropout are separate layers; an inverted residual block
-is expand 1x1 + relu6, depthwise 3x3 + relu6, project 1x1, plus the input
-when stride is 1 and the channels match; ``gap_linear`` is a global mean
-followed by a linear layer.
+(``models/cnn.py``), each kind's in its own file, ``bench/kinds/<kind>.py``
+(its ``apply``): a ``conv`` layer is convolution plus bias; relu, relu6,
+maxpool and dropout are separate layers.
 
 At each stage boundary (``cuts``) the boundary activation crosses the
 configured wire format.  ``int8`` is symmetric per-channel quantization
@@ -32,6 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from bench import spec
+
 MODES = ("highest", "bf16x3")
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -45,7 +45,7 @@ def _split(a):
     return hi, _bf16(a - hi)
 
 
-def _contract(op, a, b, mode: str):
+def contract(op, a, b, mode: str):
     """``op(a, b, precision)`` under ``mode``."""
     if mode == "highest":
         return op(a, b, HIGHEST)
@@ -54,71 +54,28 @@ def _contract(op, a, b, mode: str):
     return op(ah, bh, HIGHEST) + (op(ah, bl, HIGHEST) + op(al, bh, HIGHEST))
 
 
-def _conv(x, p, stride, pad, mode, groups=1):
+def conv(x, p, stride, pad, mode, groups=1):
+    """Convolution of the NCHW batch ``x`` plus bias."""
     def op(a, b, precision):
         return jax.lax.conv_general_dilated(
             a, b, (stride, stride), [(pad, pad), (pad, pad)],
             dimension_numbers=("NCHW", "OIHW", "NCHW"),
             feature_group_count=groups, precision=precision)
-    return _contract(op, x, p["w"], mode) + p["b"][None, :, None, None]
+    return contract(op, x, p["w"], mode) + p["b"][None, :, None, None]
 
 
-def _linear(x, p, mode):
+def linear(x, p, mode):
     def op(a, b, precision):
         return jnp.matmul(a, b, precision=precision)
-    return _contract(op, x, p["w"], mode) + p["b"]
+    return contract(op, x, p["w"], mode) + p["b"]
 
 
-def _relu6(x):
+def relu6(x):
     return jnp.clip(x, 0.0, 6.0)
 
 
-def _adaptive_avgpool(x, out: int):
-    """torch AdaptiveAvgPool2d: output i averages input
-    [floor(i*n/out), ceil((i+1)*n/out)) along each spatial axis."""
-    for axis in (2, 3):
-        n = x.shape[axis]
-        parts = []
-        for i in range(out):
-            s, e = (i * n) // out, -(-((i + 1) * n) // out)
-            parts.append(jax.lax.slice_in_dim(x, s, e, axis=axis)
-                         .mean(axis=axis, keepdims=True))
-        x = jnp.concatenate(parts, axis=axis)
-    return x
-
-
 def apply_layer(layer: dict, p, x, mode: str):
-    kind = layer["kind"]
-    if kind == "conv":
-        return _conv(x, p, int(layer.get("stride", 1)),
-                     int(layer.get("pad", 0)), mode)
-    if kind == "relu":
-        return jnp.maximum(x, 0.0)
-    if kind == "relu6":
-        return _relu6(x)
-    if kind == "dropout":
-        return x
-    if kind == "maxpool":
-        k, s = int(layer["ksize"]), int(layer.get("stride", 1))
-        return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 1, k, k),
-                                     (1, 1, s, s), "VALID")
-    if kind == "avgpool":
-        return _adaptive_avgpool(x, int(layer["out_hw"]))
-    if kind == "linear":
-        return _linear(x.reshape(x.shape[0], -1), p, mode)
-    if kind == "gap_linear":
-        return _linear(x.mean(axis=(2, 3)) if x.ndim == 4 else x, p, mode)
-    if kind == "invres":
-        y = x
-        if "expand" in p:
-            y = _relu6(_conv(y, p["expand"], 1, 0, mode))
-        stride = int(layer.get("stride", 1))
-        y = _relu6(_conv(y, p["dw"], stride, 1, mode, groups=y.shape[1]))
-        y = _conv(y, p["project"], 1, 0, mode)
-        if stride == 1 and x.shape == y.shape:
-            y = y + x
-        return y
-    raise ValueError(f"unknown layer kind {kind!r}")
+    return spec.load_kind(layer["kind"]).apply(layer, p, x, mode)
 
 
 def wire_roundtrip(x, wire: str):

@@ -11,10 +11,12 @@ clock with the device's operations.  They nest on the serving thread:
                               > wire.send
                               > wire.decode
 
-Program spans are clipped to the traced window (``tracefile.window``).
-Each idle interval of the first chip goes to the innermost program span
-that covers it, and a span's self time is its duration less what its
-child spans cover.  Each span's layer is in ``LAYERS``."""
+They are among the host events that ``tracefile.from_profile`` keeps in
+``Trace.program``.  Program spans are clipped to the traced window
+(``tracefile.window``).  Each idle interval of the first chip goes to the
+innermost program span that covers it, and a span's self time is its
+duration less what its child spans cover.  Each span's layer is in
+``LAYERS``."""
 from __future__ import annotations
 
 import argparse
@@ -42,25 +44,16 @@ LAYERS = {
 CODEC = ("wire.encode", "wire.send", "wire.decode")
 
 
-def from_profile(profile) -> list[Event]:
-    """The program spans of a ``jax.profiler.ProfileData``, by start."""
-    found = [Event(e.name, float(e.start_ns), float(e.duration_ns), {})
-             for plane in profile.planes if plane.name.startswith("/host:")
-             for line in plane.lines for e in line.events
-             if e.name in PROGRAM_SPANS]
-    return sorted(found, key=lambda e: e.start_ns)
+def program_spans(trace: Trace) -> list[Event]:
+    """The spans of ``PROGRAM_SPANS`` among ``trace.program``, by start."""
+    return [s for s in trace.program if s.name in PROGRAM_SPANS]
 
 
 def load(path: str) -> tuple[Trace, list[Event]]:
     """(``tracefile.Trace``, program spans) of one ``.xplane.pb`` file, or
     of a text-proto XSpace (``.pbtxt``)."""
-    from jax.profiler import ProfileData
-    if path.endswith(".pbtxt"):
-        with open(path) as f:
-            profile = ProfileData.from_text_proto(f.read())
-    else:
-        profile = ProfileData.from_file(path)
-    return tracefile.from_profile(profile), from_profile(profile)
+    trace = tracefile.load(path)
+    return trace, program_spans(trace)
 
 
 def innermost(program: list[Event], w0: float, w1: float

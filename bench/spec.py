@@ -1,6 +1,8 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 * a configuration ``<config>`` is ``bench/configs/<config>.json``;
+* a layer kind ``<kind>`` is ``bench/kinds/<kind>.py``: its shapes,
+  FLOPs, weights, plain reference and conv kernel calls (``load_kind``);
 * a cell's traffic mix is ``bench/traffic/<cell>.json``;
 * a metric ``<name>`` is read by ``bench/metrics/<name>.py``, or, where
   that file does not exist, by ``bench/metrics/<base>.py`` with ``<base>``
@@ -8,14 +10,16 @@
   ``ops_per_req.tput`` share ``ops_per_req.py``).  A reader module defines
   ``read(ctx) -> float | None``.
 
-A later cell, mix or metric is a new file and a new entry; no file that is
-there needs an edit."""
+A later cell, mix, layer kind or metric is a new file and a new entry; no
+file that is there needs an edit."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
+import re
 
 from bench import traffic as traffic_lib
 
@@ -75,6 +79,8 @@ def resolve(name: str, bench: dict | None = None,
                        f"{w['config']!r}")
     with open(config_path(w["config"], bench_dir)) as f:
         config = json.load(f)
+    for layer in config["layers"]:
+        kind_path(layer["kind"], bench_dir)     # fail early on a missing one
     mix = traffic_lib.load(traffic_path(name, bench_dir))
     e2e = tuple(_metric(m) for m in bench["end_to_end"] if _applies(m, name))
     per_layer = tuple(_metric(m) for m in bench["per_layer"]
@@ -93,11 +99,51 @@ def reader_path(metric: str, bench_dir: str = BENCH_DIR) -> str:
     raise FileNotFoundError(f"no reader for metric {metric!r} under {base}")
 
 
+def _exec(module_name: str, path: str):
+    mod_spec = importlib.util.spec_from_file_location(module_name, path)
+    module = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(module)
+    return module
+
+
 def load_reader(metric: str, bench_dir: str = BENCH_DIR):
     path = reader_path(metric, bench_dir)
     module_name = "bench_metric_" + os.path.basename(path)[:-3].replace(
         ".", "_")
-    mod_spec = importlib.util.spec_from_file_location(module_name, path)
-    module = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(module)
-    return module.read
+    return _exec(module_name, path).read
+
+
+def kind_path(kind: str, bench_dir: str = BENCH_DIR) -> str:
+    path = os.path.join(bench_dir, "kinds", f"{kind}.py")
+    if not re.fullmatch(r"[A-Za-z0-9][A-Za-z0-9_]*", kind) or \
+            not os.path.isfile(path):
+        raise ValueError(f"unknown layer kind {kind!r}: no file {path}")
+    return path
+
+
+@functools.lru_cache(maxsize=None)
+def load_kind(kind: str, bench_dir: str = BENCH_DIR):
+    """The module of layer kind ``kind``, loaded once per process.
+
+    A layer is the configuration file's plain dict (``{"kind": "conv",
+    "cout": 64, ...}``) and a shape is (C, H, W) or (F,), for one image.
+    The module defines:
+
+    * ``out_shape(layer, in_shape)``;
+    * ``flops_params(layer, in_shape)``: (FLOPs, parameter count);
+    * ``init(key, layer, in_shape)``: the layer's float32 weights from
+      ``key``, in the layout the program's layer walk takes (``{}`` for
+      none);
+    * ``apply(layer, p, x, mode)``: the plain reference on the batch ``x``,
+      every contraction through ``reference.contract`` under ``mode``;
+    * optionally ``launches(layers, i, in_shape, elem_bytes, fuses)``: the
+      conv kernel calls that layer ``i`` starts, as ``flops.launch`` counts
+      them, and the number of layers they consume; ``fuses(j)`` says
+      whether layer ``j`` runs in the same stage as layer ``j - 1``.  A
+      kind without it makes no call and consumes itself;
+    * optionally ``FOLDS_INTO_CONV``: ``"epilogue"`` where a conv right
+      before folds this layer into its call, ``"pool"`` where a conv and
+      its epilogue right before do (``flops.folds_into_conv``).
+
+    Kind modules import nothing from the program."""
+    return _exec(f"bench_kind_{kind}", kind_path(kind, bench_dir))

@@ -92,6 +92,30 @@ def test_clean_run_is_correct(cpu_run, config, loop):
         set(out["metrics"])
 
 
+def test_a_reader_sees_the_engine_counters(cpu_run, monkeypatch):
+    """``ctx["engine_stats"]`` holds the engine's whole ``stats()`` at the
+    window's start and after its drain, beside ``engine_window``."""
+    seen = {}
+
+    def reader(name):
+        def read(ctx):
+            seen[name] = ctx["engine_stats"], ctx["engine_window"]
+            return 1.0
+        return read
+    monkeypatch.setattr(spec, "load_reader", reader)
+    served = spec.Metric("served_rps", "req/s")
+    cell = dataclasses.replace(tiny_cell("vgg16-fp32-chain3", "xla"),
+                               end_to_end=(served,))
+    out = cpu_run(cell)
+    stats, window = seen["served_rps"]
+    assert set(stats) == {"before", "after"}
+    assert set(stats["before"]) == set(stats["after"]) >= {
+        "served", "batches", "hops", "buckets", "walk_traces"}
+    for k in ("served", "batches"):
+        assert stats["after"][k] - stats["before"][k] == window[k]
+    assert window["served"] == out["attempted"] - out["failed"] > 0
+
+
 def _altered_answer(monkeypatch):
     """Every answer has its first logit moved where it is produced, by 1%
     of its largest logit."""

@@ -78,12 +78,38 @@ def test_layers_add_up_to_the_idle_time_under_step(recorded):
     assert step_ns == pytest.approx(21_000.0)
 
 
+def test_span_readers_equal_the_split(recorded):
+    """The four readers of ``bench/run.py`` read what
+    ``bench/idle_by_span.py`` prints for the same window."""
+    from bench import spec
+    from bench.harness import Window
+    trace, program = recorded
+    steps = [(0.0, 0.018, 1), (0.024, 0.040, 1)]
+    win = Window(recs=[], seconds=1.0, served_in_window=2, steps=steps,
+                 traced_steps=steps, generator_late_s=[])
+    want = spans.split(win, trace, program)
+    ctx = {"trace": trace, "window": win}
+    for name in ("idle_walk", "idle_runtime", "idle_engine", "codec_ms"):
+        for suffix in ("lat", "tput"):
+            got = spec.load_reader(f"{name}.{suffix}")(ctx)
+            assert got == want[name] and got > 0, name
+    assert want["codec_ms"] == pytest.approx(3.5e-3)
+
+
 def test_nothing_is_read_from_a_trace_without_program_spans():
     trace, program = spans.load(SMALL_TRACE)
     assert program == [] and len(trace.spans) == 5
     for layer in spans.LAYERS:
         assert spans.idle_share(trace, program, layer) is None
     assert spans.codec_ms(trace, program, served=2) is None
+    from bench import spec
+    from bench.harness import Window
+    win = Window(recs=[], seconds=1.0, served_in_window=2, steps=[],
+                 traced_steps=[(0.0, 0.004, 2)], generator_late_s=[])
+    for name in ("idle_walk", "idle_runtime", "idle_engine", "codec_ms"):
+        read = spec.load_reader(f"{name}.tput")
+        assert read({"trace": trace, "window": win}) is None
+        assert read({"trace": None, "window": win}) is None
 
 
 def test_innermost_pieces_partition_what_the_spans_cover(recorded):

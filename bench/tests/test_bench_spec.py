@@ -9,7 +9,7 @@ import pytest
 
 from bench import flops, peaks, spec, traffic
 
-MODELS = {"vgg16-fp32-chain3": "vgg16", "mbv2-fp32-chain3-int8": "mobilenetv2"}
+CONFIGS = [c["name"] for c in spec.load_benchmark()["configs"]]
 
 
 @pytest.mark.parametrize("rate,seconds", [(80.0, 20.0), (43.5, 7.0)])
@@ -62,7 +62,7 @@ def test_knee_is_highest_rate_that_keeps_up():
     assert knee == 80     # 90 answered under 99%; at 100 the queue grew
 
 
-@pytest.mark.parametrize("config", sorted(MODELS))
+@pytest.mark.parametrize("config", CONFIGS)
 def test_flop_counter_matches_program_at_every_layer(config):
     from repro.models import cnn
     with open(spec.config_path(config)) as f:
@@ -72,7 +72,7 @@ def test_flop_counter_matches_program_at_every_layer(config):
     # torchvision's MobileNetV2 has a ReLU6 after the stem and the last conv
     program = [cnn.Layer(**mine) for mine in layers
                if not (config.startswith("mbv2") and mine["kind"] == "relu6")]
-    assert program == cnn.CNN_MODELS[MODELS[config]]
+    assert program == cnn.CNN_MODELS[cfg["model"]]
     shape = tuple(cfg["in_shape"])
     assert shape == cnn.INPUT_SHAPE
     for mine in layers:

@@ -82,3 +82,49 @@ def test_conv_roofline_is_not_read_when_calls_do_not_add_up(trace):
         is None
     assert spec.load_reader("conv_roofline.lat")(
         dict(_ctx(trace), trace=None)) is None
+
+
+NEW_SPAN_TRACE = """
+planes {
+  id: 1
+  name: "/host:CPU"
+  lines {
+    id: 1
+    name: "python"
+    timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 9000000 }
+    events { metadata_id: 2 offset_ps: 1000000 duration_ps: 2000000 }
+    events { metadata_id: 3 offset_ps: 4000000 duration_ps: 1000000 }
+    events { metadata_id: 2 offset_ps: 6000000 duration_ps: 500000 }
+    events { metadata_id: 4 offset_ps: 7000000 duration_ps: 500000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "step" } }
+  event_metadata { key: 2 value { id: 2 name: "cache.lookup" } }
+  event_metadata { key: 3 value { id: 3 name: "Cache.Lookup" } }
+  event_metadata { key: 4 value { id: 4 name: "lookup" } }
+}
+"""
+
+LOOKUP_READER = """
+def read(ctx):
+    found = [s for s in ctx["trace"].program if s.name == "cache.lookup"]
+    return sum(s.dur_ns for s in found) / 1e6 if found else None
+"""
+
+
+def test_a_new_program_span_reaches_a_new_reader(tmp_path):
+    """A span the program does not have yet, named in the program's span
+    form, reaches a reader file that only a later change would add; names
+    of another form stay out of ``Trace.program``."""
+    path = tmp_path / "new_span.pbtxt"
+    path.write_text(NEW_SPAN_TRACE)
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "lookup_ms.py").write_text(LOOKUP_READER)
+    trace = tracefile.load(str(path))
+    assert [s.name for s in trace.spans] == ["step"]
+    assert [(s.name, s.start_ns) for s in trace.program] == [
+        ("cache.lookup", 2000.0), ("cache.lookup", 7000.0)]
+    read = spec.load_reader("lookup_ms.lat", bench_dir=str(tmp_path))
+    assert read({"trace": trace}) == pytest.approx(2.5e-3)
+    # the harness's spans alone: the reader finds nothing and says so
+    assert read({"trace": tracefile.load(TRACE)}) is None

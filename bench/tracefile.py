@@ -3,10 +3,14 @@ time and idle gaps.
 
 The harness writes its own host spans (``HOST_SPANS``) with
 ``jax.profiler.TraceAnnotation``; the device's operations come from the
-``XLA Ops`` line of each ``/device:TPU:<n>`` plane.  Both lie on the
-trace's one timeline.  The traced window runs from the start of the first
-harness span to the end of the last, and every device operation is
-clipped to it."""
+``XLA Ops`` line of each ``/device:TPU:<n>`` plane.  The program's own
+spans are the host events whose name has the program's span form
+(``PROGRAM_SPAN``): lowercase words joined by dots, at least two
+(``engine.step``, ``wire.sync``).  A span the program adds later is kept
+as long as its name has that form, and a reader finds it in
+``Trace.program``.  All of them lie on the trace's one timeline.  The
+traced window runs from the start of the first harness span to the end of
+the last, and every device operation is clipped to it."""
 from __future__ import annotations
 
 import bisect
@@ -16,6 +20,7 @@ import os
 import re
 
 HOST_SPANS = ("submit", "step", "wait_logits", "idle_no_request")
+PROGRAM_SPAN = re.compile(r"[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 
@@ -36,6 +41,8 @@ class Event:
 class Trace:
     devices: list[list[Event]]    # per chip: its device ops, by start
     spans: list[Event]            # the harness's host spans, by start
+    # the program's host spans (``PROGRAM_SPAN``), by start
+    program: list[Event] = dataclasses.field(default_factory=list)
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -57,7 +64,7 @@ def _stats(event) -> dict:
 
 def from_profile(profile) -> Trace:
     """Build a ``Trace`` from a ``jax.profiler.ProfileData``."""
-    devices, spans = [], []
+    devices, spans, program = [], [], []
     for plane in profile.planes:
         if DEVICE_PLANE.match(plane.name):
             ops = [Event(e.name, float(e.start_ns), float(e.duration_ns),
@@ -66,11 +73,16 @@ def from_profile(profile) -> Trace:
                    for e in line.events]
             devices.append(sorted(ops, key=lambda e: e.start_ns))
         elif plane.name.startswith("/host:"):
-            spans += [Event(e.name, float(e.start_ns), float(e.duration_ns),
-                            {})
-                      for line in plane.lines for e in line.events
-                      if e.name in HOST_SPANS]
-    return Trace(devices, sorted(spans, key=lambda e: e.start_ns))
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in HOST_SPANS:
+                        spans.append(Event(e.name, float(e.start_ns),
+                                           float(e.duration_ns), {}))
+                    elif PROGRAM_SPAN.fullmatch(e.name):
+                        program.append(Event(e.name, float(e.start_ns),
+                                             float(e.duration_ns), {}))
+    return Trace(devices, sorted(spans, key=lambda e: e.start_ns),
+                 sorted(program, key=lambda e: e.start_ns))
 
 
 def load(path: str) -> Trace:

@@ -5,8 +5,10 @@ storage dtype of every configuration so far).  The layout is the one the
 program's layer walk takes: a list with one entry per layer, a conv as
 ``{"w": (cout, cin/groups, k, k), "b": (cout,)}``, a linear as
 ``{"w": (fin, fout), "b": (fout,)}``, an inverted residual block as
-``{"expand"?, "dw", "project"}``.  The plain reference reads the same
-arrays; neither side makes weights of its own.
+``{"expand"?, "dw", "project"}``; each kind's ``init`` in
+``bench/kinds/<kind>.py`` makes its own from the key it is given.  The
+plain reference reads the same arrays; neither side makes weights of its
+own.
 
 Weights are He-normal; biases are small and non-zero (a deployed network
 has batch norm folded into them), so the bias path is exercised."""
@@ -19,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import flops
+from bench import flops, spec
 
 BIAS_STD = 0.01
 
@@ -32,7 +34,8 @@ def key_of(seed: int, stream: int):
                                     impl="threefry2x32")
 
 
-def _conv(key, cin_pg, cout, k):
+def conv_params(key, cin_pg, cout, k):
+    """A conv's He-normal weights (cout, cin/groups, k, k) and bias."""
     kw, kb = jax.random.split(key)
     w = jax.random.normal(kw, (cout, cin_pg, k, k), jnp.float32) \
         * math.sqrt(2.0 / (cin_pg * k * k))
@@ -40,32 +43,12 @@ def _conv(key, cin_pg, cout, k):
                                                       jnp.float32)}
 
 
-def _linear(key, fin, fout):
+def linear_params(key, fin, fout):
+    """A linear layer's He-normal weights (fin, fout) and bias."""
     kw, kb = jax.random.split(key)
     w = jax.random.normal(kw, (fin, fout), jnp.float32) * math.sqrt(2.0 / fin)
     return {"w": w, "b": BIAS_STD * jax.random.normal(kb, (fout,),
                                                       jnp.float32)}
-
-
-def _layer(key, layer: dict, shape: tuple):
-    kind = layer["kind"]
-    if kind == "conv":
-        return _conv(key, shape[0], int(layer["cout"]), int(layer["ksize"]))
-    if kind == "linear":
-        return _linear(key, math.prod(shape), int(layer["features"]))
-    if kind == "gap_linear":
-        return _linear(key, shape[0], int(layer["features"]))
-    if kind == "invres":
-        cin = shape[0]
-        hidden = cin * int(layer["expand"])
-        k0, k1, k2 = jax.random.split(key, 3)
-        p = {}
-        if int(layer["expand"]) != 1:
-            p["expand"] = _conv(k0, cin, hidden, 1)
-        p["dw"] = _conv(k1, 1, hidden, 3)
-        p["project"] = _conv(k2, hidden, int(layer["cout"]), 1)
-        return p
-    return {}
 
 
 @functools.lru_cache(maxsize=None)
@@ -75,7 +58,7 @@ def _params_fn(layers: tuple, in_shape: tuple):
 
     def build(key):
         keys = jax.random.split(key, len(layer_dicts))
-        return [_layer(keys[i], layer, shapes[i])
+        return [spec.load_kind(layer["kind"]).init(keys[i], layer, shapes[i])
                 for i, layer in enumerate(layer_dicts)]
     return jax.jit(build)
 
