@@ -61,7 +61,8 @@ def model_flops(layers: list[dict], in_shape: tuple) -> float:
 
 def launch(cin, h, w, cout, k, s, p, groups, pool, elem_bytes) -> dict:
     """One conv kernel call on a (cin, h, w) input, its output pooled by
-    ``pool`` = (ksize, stride) where that is not None."""
+    ``pool`` = (ksize, stride) where that is not None: its ``flops``,
+    ``bytes`` and ``groups`` (``cin`` for a depthwise call)."""
     oh, ow = conv_out(h, k, s, p), conv_out(w, k, s, p)
     flops = 2.0 * k * k * (cin // groups) * cout * oh * ow
     if pool:
@@ -69,7 +70,7 @@ def launch(cin, h, w, cout, k, s, p, groups, pool, elem_bytes) -> dict:
         oh, ow = conv_out(oh, pk, ps, 0), conv_out(ow, pk, ps, 0)
     weights = k * k * (cin // groups) * cout + cout
     nbytes = elem_bytes * (cin * h * w + weights + cout * oh * ow)
-    return {"flops": flops, "bytes": float(nbytes)}
+    return {"flops": flops, "bytes": float(nbytes), "groups": groups}
 
 
 def folds_into_conv(layer: dict) -> str | None:

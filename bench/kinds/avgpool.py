@@ -8,6 +8,8 @@ import jax.numpy as jnp
 
 from bench.flops import field
 
+EXAMPLE = {"kind": "avgpool", "out_hw": 4}
+
 
 def out_shape(layer, in_shape):
     return (in_shape[0], field(layer, "out_hw"), field(layer, "out_hw"))

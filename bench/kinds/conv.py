@@ -7,6 +7,8 @@ import math
 from bench import flops, reference, weights
 from bench.flops import field
 
+EXAMPLE = {"kind": "conv", "cout": 6, "ksize": 3, "stride": 2, "pad": 1}
+
 
 def out_shape(layer, in_shape):
     _, h, w = in_shape

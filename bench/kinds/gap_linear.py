@@ -5,6 +5,8 @@ import math
 from bench import reference, weights
 from bench.flops import field
 
+EXAMPLE = {"kind": "gap_linear", "features": 5}
+
 
 def out_shape(layer, in_shape):
     return (field(layer, "features"),)

@@ -8,6 +8,8 @@ import jax
 from bench import flops, reference, weights
 from bench.flops import field
 
+EXAMPLE = {"kind": "invres", "cout": 4, "expand": 6}
+
 
 def out_shape(layer, in_shape):
     _, h, w = in_shape

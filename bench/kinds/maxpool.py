@@ -10,6 +10,7 @@ from bench import flops
 from bench.flops import field
 
 FOLDS_INTO_CONV = "pool"
+EXAMPLE = {"kind": "maxpool", "ksize": 3, "stride": 2}
 
 
 def out_shape(layer, in_shape):

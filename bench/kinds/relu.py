@@ -4,6 +4,7 @@ import math
 import jax.numpy as jnp
 
 FOLDS_INTO_CONV = "epilogue"
+EXAMPLE = {"kind": "relu"}
 
 
 def out_shape(layer, in_shape):

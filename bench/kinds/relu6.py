@@ -5,6 +5,7 @@ import math
 from bench import reference
 
 FOLDS_INTO_CONV = "epilogue"
+EXAMPLE = {"kind": "relu6"}
 
 
 def out_shape(layer, in_shape):

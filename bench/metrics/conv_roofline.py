@@ -9,7 +9,11 @@ after the program's jitted wrapper (``kernels/ops.py::_conv2d``), as
 of the layer walk, and the int8 codec's kernel is ``_quantize.<n>``.
 Every request served in the traced window makes the same calls, so the
 traced calls must number requests x calls per request; where they do not,
-the share is not read."""
+the share is not read.  The calls are counted over the whole trace and
+not clipped to the host spans' window: the profiler runs from just before
+the first traced step to just after the last one waited for its logits,
+so every conv call it holds is one of those steps', while the device's
+timeline can sit a millisecond or so early against the host's."""
 import sys
 
 from bench import tracefile
@@ -23,7 +27,7 @@ def read(ctx):
     launches = ctx["conv_launches"]
     if trace is None or peak is None or not served or not launches:
         return None
-    events = tracefile.kernel_events(trace, KERNEL)
+    events = tracefile.kernel_events(trace, KERNEL, whole=True)
     if len(events) != served * len(launches):
         print(f"conv_roofline: {len(events)} conv kernel events for "
               f"{served} requests x {len(launches)} calls; not read",

@@ -3,6 +3,8 @@
 * a configuration ``<config>`` is ``bench/configs/<config>.json``;
 * a layer kind ``<kind>`` is ``bench/kinds/<kind>.py``: its shapes,
   FLOPs, weights, plain reference and conv kernel calls (``load_kind``);
+* a configuration's CPU checks are ``bench/tests/data/configs/<config>.json``
+  (``load_checks``), read by the tests and never by a run;
 * a cell's traffic mix is ``bench/traffic/<cell>.json``;
 * a metric ``<name>`` is read by ``bench/metrics/<name>.py``, or, where
   that file does not exist, by ``bench/metrics/<base>.py`` with ``<base>``
@@ -10,8 +12,8 @@
   ``ops_per_req.tput`` share ``ops_per_req.py``).  A reader module defines
   ``read(ctx) -> float | None``.
 
-A later cell, mix, layer kind or metric is a new file and a new entry; no
-file that is there needs an edit."""
+A later configuration, cell, mix, layer kind or metric is new files and a
+new entry; no file that is there needs an edit."""
 from __future__ import annotations
 
 import dataclasses
@@ -58,6 +60,30 @@ def _metric(m: dict) -> Metric:
 
 def config_path(name: str, bench_dir: str = BENCH_DIR) -> str:
     return os.path.join(bench_dir, "configs", f"{name}.json")
+
+
+def load_checks(config: str, bench_dir: str = BENCH_DIR) -> dict:
+    """What the CPU tests hold configuration ``config`` to:
+
+    * ``tiny``: ``in_shape`` and ``layers`` of a small stand-in with the
+      configuration's kinds of layers, chain, wire and checks, which a
+      whole run on the CPU serves;
+    * ``pins``: ``cuts`` and ``wires`` of the stand-in, its per-layer
+      weight sums (``sums``) of seed 0, and the reference logits of two
+      images of seed 0 under each of ``reference.MODES``;
+    * ``control_cuts``: the cuts at which the control is read, over the
+      configuration's own layers at 64 px;
+    * ``totals``: ``flops`` per image at ``in_shape`` within ``flops_rel``,
+      and ``conv_calls``, the conv kernel calls of one request at ``cuts``;
+    * ``program_lacks``: indices of the configuration's layers that the
+      program's model (its ``model`` key) does not have."""
+    path = os.path.join(bench_dir, "tests", "data", "configs",
+                        f"{config}.json")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"configuration {config!r} has no CPU "
+                                f"checks: no file {path}")
+    with open(path) as f:
+        return json.load(f)
 
 
 def traffic_path(cell: str, bench_dir: str = BENCH_DIR) -> str:
@@ -143,7 +169,10 @@ def load_kind(kind: str, bench_dir: str = BENCH_DIR):
       kind without it makes no call and consumes itself;
     * optionally ``FOLDS_INTO_CONV``: ``"epilogue"`` where a conv right
       before folds this layer into its call, ``"pool"`` where a conv and
-      its epilogue right before do (``flops.folds_into_conv``).
+      its epilogue right before do (``flops.folds_into_conv``);
+    * ``EXAMPLE``: one layer of the kind, which the tests hold against the
+      program's own layer at the input ``EXAMPLE_IN_SHAPE`` (optional,
+      (4, 9, 9) where it is not set).  A run does not read it.
 
     Kind modules import nothing from the program."""
     return _exec(f"bench_kind_{kind}", kind_path(kind, bench_dir))

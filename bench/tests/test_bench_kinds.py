@@ -1,7 +1,9 @@
-"""Layer kinds as files (``bench/kinds/<kind>.py``): each of the nine
-agrees with the program's own layer arithmetic, a new kind is one new file,
-and the weights and reference logits the kinds make are the ones pinned in
-``data/tiny_pins.json``, recorded before the kinds moved into files."""
+"""Layer kinds as files (``bench/kinds/<kind>.py``): each kind file's
+``EXAMPLE`` layer agrees with the program's own layer arithmetic, a new kind
+is one new file, and the weights and reference logits the kinds make are
+the ones pinned in each configuration's data file
+(``data/configs/<config>.json``), recorded before the kinds moved into
+files."""
 import json
 import os
 import shutil
@@ -14,48 +16,51 @@ import pytest
 
 from bench import flops, spec
 
-DATA = os.path.join(os.path.dirname(__file__), "data")
 IN_SHAPE = (4, 9, 9)
-# one layer of each kind, at a small odd shape
-KIND_LAYERS = {
-    "conv": {"kind": "conv", "cout": 6, "ksize": 3, "stride": 2, "pad": 1},
-    "relu": {"kind": "relu"},
-    "relu6": {"kind": "relu6"},
-    "maxpool": {"kind": "maxpool", "ksize": 3, "stride": 2},
-    "avgpool": {"kind": "avgpool", "out_hw": 4},
-    "dropout": {"kind": "dropout"},
-    "linear": {"kind": "linear", "features": 5},
-    "gap_linear": {"kind": "gap_linear", "features": 5},
-    "invres": {"kind": "invres", "cout": 4, "expand": 6},
-}
 REQUIRED = ("out_shape", "flops_params", "init", "apply")
+KINDS = sorted(name[:-3] for name in os.listdir(
+    os.path.join(spec.BENCH_DIR, "kinds")) if name.endswith(".py"))
+CONFIGS = [c["name"] for c in spec.load_benchmark()["configs"]]
 
 
-@pytest.mark.parametrize("kind", sorted(KIND_LAYERS))
+def example(kind: str, bench_dir: str = spec.BENCH_DIR):
+    """The kind file's ``EXAMPLE`` layer and its input shape
+    (``EXAMPLE_IN_SHAPE``, else ``IN_SHAPE``)."""
+    module = spec.load_kind(kind, bench_dir)
+    if not hasattr(module, "EXAMPLE"):
+        pytest.fail(f"{spec.kind_path(kind, bench_dir)} has no EXAMPLE "
+                    f"layer to hold against the program")
+    return module.EXAMPLE, tuple(getattr(module, "EXAMPLE_IN_SHAPE",
+                                         IN_SHAPE))
+
+
+@pytest.mark.parametrize("kind", KINDS)
 def test_kind_file_agrees_with_the_program(kind):
-    """Shapes, FLOPs and parameters as ``models/cnn.py`` counts them, the
-    weights' layout as the program's walk takes it, and the reference's
-    output as the program's XLA path computes it."""
+    """On the kind file's ``EXAMPLE`` layer: shapes, FLOPs and parameters
+    as ``models/cnn.py`` counts them, the weights' layout as the program's
+    walk takes it, and the reference's output as the program's XLA path
+    computes it."""
     import jax
     import jax.numpy as jnp
     from repro.models import cnn
     module = spec.load_kind(kind)
     for name in REQUIRED:
         assert callable(getattr(module, name)), name
-    layer, theirs = KIND_LAYERS[kind], cnn.Layer(**KIND_LAYERS[kind])
-    assert module.out_shape(layer, IN_SHAPE) == \
-        tuple(cnn.layer_out_shape(theirs, IN_SHAPE))
-    assert module.flops_params(layer, IN_SHAPE) == \
-        cnn.layer_flops_params(theirs, IN_SHAPE)
+    layer, shape = example(kind)
+    assert layer["kind"] == kind
+    theirs = cnn.Layer(**layer)
+    assert module.out_shape(layer, shape) == \
+        tuple(cnn.layer_out_shape(theirs, shape))
+    assert module.flops_params(layer, shape) == \
+        cnn.layer_flops_params(theirs, shape)
     key = jax.random.PRNGKey(3)
-    mine = module.init(key, layer, IN_SHAPE)
+    mine = module.init(key, layer, shape)
     assert jax.tree_util.tree_structure(mine) == \
-        jax.tree_util.tree_structure(cnn.init_layer(key, theirs, IN_SHAPE))
+        jax.tree_util.tree_structure(cnn.init_layer(key, theirs, shape))
     assert [leaf.shape for leaf in jax.tree_util.tree_leaves(mine)] == \
         [leaf.shape for leaf in jax.tree_util.tree_leaves(
-            cnn.init_layer(key, theirs, IN_SHAPE))]
-    x = jax.random.normal(jax.random.PRNGKey(4), (2,) + IN_SHAPE,
-                          jnp.float32)
+            cnn.init_layer(key, theirs, shape))]
+    x = jax.random.normal(jax.random.PRNGKey(4), (2,) + shape, jnp.float32)
     want = cnn.apply_layer(theirs, mine, x, backend="xla", dtype="fp32")
     got = module.apply(layer, mine, x, "highest")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -149,28 +154,38 @@ def test_a_new_kind_is_one_new_file(tmp_path):
     assert out["gap"] < 1e-6
 
 
-PIN_CUTS = {"vgg16-fp32-chain3": ((3, 8), ("fp32", "fp32")),
-            "mbv2-fp32-chain3-int8": ((2, 5), ("int8", "int8"))}
+def test_a_kind_file_without_an_example_is_named(tmp_path):
+    """The kind test fails on a kind file that has no ``EXAMPLE``, and
+    says which file."""
+    kinds = tmp_path / "kinds"
+    kinds.mkdir()
+    (kinds / "scale.py").write_text(SCALE_KIND)
+    want = os.path.join(str(kinds), "scale.py")
+    with pytest.raises(pytest.fail.Exception, match="has no EXAMPLE") as e:
+        example("scale", str(tmp_path))
+    assert want in str(e.value)
+    shutil.copy(spec.kind_path("relu"), kinds / "relu.py")
+    assert example("relu", str(tmp_path)) == ({"kind": "relu"}, IN_SHAPE)
 
 
-@pytest.mark.parametrize("config", sorted(PIN_CUTS))
+@pytest.mark.parametrize("config", CONFIGS)
 def test_weights_and_reference_are_pinned(config):
     """Per-layer weight sums of seed 0 and the reference logits of two
-    images of seed 0 (both modes, the wire at two cuts) for the tiny
-    stand-ins of ``test_bench_run.py``."""
+    images of seed 0 (both modes, the wire at the pinned cuts) for the
+    tiny stand-in of the configuration's data file."""
     import jax
     import jax.numpy as jnp
     from bench import reference, weights
-    from bench.tests.test_bench_run import TINY_LAYERS
-    with open(os.path.join(DATA, "tiny_pins.json")) as f:
-        pins = json.load(f)[config]
-    layers, shape = TINY_LAYERS[config], (3, 16, 16)
+    checks = spec.load_checks(config)
+    pins = checks["pins"]
+    layers = checks["tiny"]["layers"]
+    shape = tuple(checks["tiny"]["in_shape"])
     params = weights.make_params(0, layers, shape)
     sums = [sum(float(jnp.sum(leaf)) for leaf in jax.tree_util.tree_leaves(p))
             for p in params]
     np.testing.assert_allclose(sums, pins["sums"], rtol=1e-6)
     x = jnp.stack(weights.make_images(0, 2, shape))
-    cuts, wires = PIN_CUTS[config]
+    cuts, wires = tuple(pins["cuts"]), tuple(pins["wires"])
     for mode in reference.MODES:
         got = reference.forward(layers, params, x, cuts=cuts, wires=wires,
                                 mode=mode)

@@ -14,28 +14,10 @@ import pytest
 
 from bench import harness, spec
 
-CONV = {"kind": "conv", "cout": 8, "ksize": 3, "pad": 1}
-# tiny stand-ins of the two configurations: the same kinds of layers,
-# chain, wire and checks, at 16 px and a few channels
-TINY_LAYERS = {
-    "vgg16-fp32-chain3": [
-        CONV, {"kind": "relu"}, CONV, {"kind": "relu"},
-        {"kind": "maxpool", "ksize": 2, "stride": 2},
-        dict(CONV, cout=16), {"kind": "relu"},
-        {"kind": "maxpool", "ksize": 2, "stride": 2},
-        {"kind": "avgpool", "out_hw": 2}, {"kind": "linear", "features": 32},
-        {"kind": "relu"}, {"kind": "dropout"},
-        {"kind": "linear", "features": 10}],
-    "mbv2-fp32-chain3-int8": [
-        {"kind": "conv", "cout": 8, "ksize": 3, "stride": 2, "pad": 1},
-        {"kind": "relu6"}, {"kind": "invres", "cout": 8, "expand": 1},
-        {"kind": "invres", "cout": 12, "stride": 2, "expand": 6},
-        {"kind": "invres", "cout": 12, "expand": 6},
-        {"kind": "conv", "cout": 32, "ksize": 1}, {"kind": "relu6"},
-        {"kind": "dropout"},
-        {"kind": "gap_linear", "features": 10}],
-}
-
+# every configuration of BENCHMARK.json, each run as the small stand-in that
+# its data file (spec.load_checks) gives: the same kinds of layers, chain,
+# wire and checks, at a few pixels and channels
+CONFIGS = [c["name"] for c in spec.load_benchmark()["configs"]]
 
 MIXES = {"open": {"loop": "open", "rate_rps": 40.0, "images": 16},
          "closed": {"loop": "closed", "clients": 4, "images": 16}}
@@ -45,7 +27,8 @@ def tiny_cell(config: str, backend: str = "pallas", rate: float = 40.0,
               samples: int = 8, loop: str = "open"):
     with open(spec.config_path(config)) as f:
         cfg = json.load(f)
-    cfg.update(in_shape=[3, 16, 16], layers=TINY_LAYERS[config],
+    tiny = spec.load_checks(config)["tiny"]
+    cfg.update(in_shape=tiny["in_shape"], layers=tiny["layers"],
                backend=backend, check=dict(cfg["check"], samples=samples))
     mix = dict(MIXES[loop], **({"rate_rps": rate} if loop == "open" else {}))
     bench = spec.load_benchmark()
@@ -76,7 +59,7 @@ def cpu_run(tmp_path, monkeypatch):
 
 
 @pytest.mark.parametrize("loop", sorted(MIXES))
-@pytest.mark.parametrize("config", sorted(TINY_LAYERS))
+@pytest.mark.parametrize("config", CONFIGS)
 def test_clean_run_is_correct(cpu_run, config, loop):
     out = cpu_run(tiny_cell(config, loop=loop))
     assert out["correct"], out["checks"]
@@ -146,7 +129,7 @@ def _half_batch(monkeypatch):
     monkeypatch.setattr(runtime.ChainRuntime, "infer", broken)
 
 
-@pytest.mark.parametrize("config", sorted(TINY_LAYERS))
+@pytest.mark.parametrize("config", CONFIGS)
 @pytest.mark.parametrize("fault", [_altered_answer, _half_batch])
 def test_broken_path_is_not_correct(cpu_run, monkeypatch, fault, config):
     fault(monkeypatch)
@@ -158,14 +141,19 @@ def test_broken_path_is_not_correct(cpu_run, monkeypatch, fault, config):
     assert any(c["value"] > c["limit"] for c in out["checks"].values())
 
 
-@pytest.mark.parametrize("config", sorted(TINY_LAYERS))
+@pytest.mark.parametrize("config", CONFIGS)
 def test_conv_launches_match_the_walk(monkeypatch, config):
     """The benchmark's count of conv kernel calls per request, which the
     roofline reader holds the trace's calls against, is the number of
     calls one served request makes through the engine."""
     import jax
     from repro.kernels import ops
+    from repro.models import cnn
     from bench import flops, weights
+    # the spy sees the calls only while a stage's walk is traced: start from
+    # an empty program cache, so that a configuration whose stand-in another
+    # one shares is traced too, and keep these programs out of the cache
+    monkeypatch.setattr(cnn, "_WALKS", {})
     cfg = tiny_cell(config).config
     shape = tuple(cfg["in_shape"])
     params = weights.make_params(7, cfg["layers"], shape)
@@ -183,22 +171,23 @@ def test_conv_launches_match_the_walk(monkeypatch, config):
     jax.block_until_ready(req.logits)
     cuts = tuple(engine.stats()["buckets"][0]["cuts"])
     assert req.status == "served"
-    assert len(calls) == len(flops.conv_launches(cfg["layers"], shape, 4,
-                                                 cuts))
+    assert calls == [c["groups"] for c in flops.conv_launches(
+        cfg["layers"], shape, 4, cuts)]
 
 
-@pytest.mark.parametrize("config,cuts", [("vgg16-fp32-chain3", (17, 31)),
-                                         ("mbv2-fp32-chain3-int8", (6, 16))])
-def test_control_reads_above_the_limit(config, cuts):
+@pytest.mark.parametrize("config", CONFIGS)
+def test_control_reads_above_the_limit(config):
     """The control, the reference at three bf16 passes per contraction in
     the program's place, fails the configuration's limit on every seed:
-    the configuration's own layers and cuts, at 64 px to fit a test."""
+    the configuration's own layers at its data file's ``control_cuts``, at
+    64 px to fit a test."""
     import jax.numpy as jnp
     from bench import reference, weights
     with open(spec.config_path(config)) as f:
         cfg = json.load(f)
     layers, shape = cfg["layers"], (3, 64, 64)
-    kw = dict(cuts=cuts, wires=harness.wire_formats(cfg, 2))
+    cuts = tuple(spec.load_checks(config)["control_cuts"])
+    kw = dict(cuts=cuts, wires=harness.wire_formats(cfg, len(cuts)))
     for seed in (3, 4, 2**31 + 17):
         params = weights.make_params(seed, layers, shape)
         x = jnp.stack(weights.make_images(seed, 8, shape))

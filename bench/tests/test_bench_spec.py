@@ -1,8 +1,13 @@
 """The benchmark's yardstick on the CPU: traffic generator, FLOP counter,
 peaks table and the files ``BENCHMARK.json`` names."""
+import collections
 import json
 import math
 import os
+import re
+import shutil
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -64,17 +69,19 @@ def test_knee_is_highest_rate_that_keeps_up():
 
 @pytest.mark.parametrize("config", CONFIGS)
 def test_flop_counter_matches_program_at_every_layer(config):
+    """At the configuration's own input size, layer by layer; the layers
+    its data file lists under ``program_lacks`` are the file's only
+    departures from the program's model (torchvision's MobileNetV2 has a
+    ReLU6 after the stem and the last conv, the program's has not)."""
     from repro.models import cnn
     with open(spec.config_path(config)) as f:
         cfg = json.load(f)
     layers = cfg["layers"]
-    # the program's paper layers, and the file's only departure from them:
-    # torchvision's MobileNetV2 has a ReLU6 after the stem and the last conv
-    program = [cnn.Layer(**mine) for mine in layers
-               if not (config.startswith("mbv2") and mine["kind"] == "relu6")]
+    lacks = set(spec.load_checks(config)["program_lacks"])
+    program = [cnn.Layer(**mine) for i, mine in enumerate(layers)
+               if i not in lacks]
     assert program == cnn.CNN_MODELS[cfg["model"]]
     shape = tuple(cfg["in_shape"])
-    assert shape == cnn.INPUT_SHAPE
     for mine in layers:
         theirs = cnn.Layer(**mine)
         assert flops.layer_flops_params(mine, shape) == \
@@ -84,19 +91,20 @@ def test_flop_counter_matches_program_at_every_layer(config):
         shape = nxt
 
 
-def test_model_totals():
-    with open(spec.config_path("vgg16-fp32-chain3")) as f:
-        vgg = json.load(f)
-    with open(spec.config_path("mbv2-fp32-chain3-int8")) as f:
-        mbv2 = json.load(f)
-    assert flops.model_flops(vgg["layers"], (3, 224, 224)) == \
-        pytest.approx(30.96e9, rel=1e-3)
-    assert flops.model_flops(mbv2["layers"], (3, 224, 224)) == \
-        pytest.approx(0.61e9, rel=0.02)
-    assert len(flops.conv_launches(vgg["layers"], (3, 224, 224), 4,
-                                   (17, 31))) == 13
-    assert len(flops.conv_launches(mbv2["layers"], (3, 224, 224), 4,
-                                   (6, 16))) == 52
+@pytest.mark.parametrize("config", CONFIGS)
+def test_model_totals(config):
+    """FLOPs per image at the configuration's input size, and the conv
+    kernel calls of one request at the stated cuts, as its data file
+    gives them."""
+    with open(spec.config_path(config)) as f:
+        cfg = json.load(f)
+    totals = spec.load_checks(config)["totals"]
+    shape = tuple(cfg["in_shape"])
+    assert flops.model_flops(cfg["layers"], shape) == \
+        pytest.approx(totals["flops"], rel=totals["flops_rel"])
+    assert len(flops.conv_launches(cfg["layers"], shape, 4,
+                                   tuple(totals["cuts"]))) == \
+        totals["conv_calls"]
 
 
 def test_conv_launches_split_fusion_at_a_cut():
@@ -109,6 +117,12 @@ def test_conv_launches_split_fusion_at_a_cut():
     # the pooled output (4x4x4) vs the unpooled one (4x8x8)
     assert fused[0]["bytes"] == 4 * (2 * 64 + 9 * 2 * 4 + 4 + 4 * 16)
     assert cut[0]["bytes"] == 4 * (2 * 64 + 9 * 2 * 4 + 4 + 4 * 64)
+    assert fused[0]["groups"] == cut[0]["groups"] == 1
+    # an inverted residual block: expand, depthwise (one group a channel),
+    # project
+    block = [{"kind": "invres", "cout": 4, "stride": 2, "expand": 6}]
+    assert [c["groups"] for c in flops.conv_launches(block, (2, 8, 8), 4)] \
+        == [1, 12, 1]
 
 
 def test_peaks_refuse_an_unknown_device():
@@ -135,3 +149,62 @@ def test_every_cell_resolves_to_its_files():
         spec.resolve("no-such-model.steady", bench)
     with pytest.raises(FileNotFoundError):
         spec.reader_path("no_such_metric.lat")
+
+
+def test_a_configuration_without_its_data_file_is_named(tmp_path):
+    want = os.path.join("bench", "tests", "data", "configs",
+                        "no-such-model.json")
+    with pytest.raises(FileNotFoundError, match="no-such-model") as e:
+        spec.load_checks("no-such-model")
+    assert want in str(e.value)
+    with pytest.raises(FileNotFoundError, match="has no CPU checks"):
+        spec.load_checks(CONFIGS[0], str(tmp_path))
+
+
+# the program's MobileNetV2 (the configuration's layers but the two ReLU6
+# that torchvision adds) at 96 px, with the configuration's tiny stand-in
+PROBE = "mbv2-probe96"
+PER_CONFIG_TESTS = {
+    "test_clean_run_is_correct": 2, "test_broken_path_is_not_correct": 2,
+    "test_conv_launches_match_the_walk": 1,
+    "test_control_reads_above_the_limit": 1,
+    "test_weights_and_reference_are_pinned": 1, "test_model_totals": 1,
+    "test_flop_counter_matches_program_at_every_layer": 1}
+
+
+def test_a_new_configuration_is_new_files(tmp_path):
+    """A configuration placed in a copy of ``bench/`` and
+    ``BENCHMARK.json`` as its file, its data file and an entry in
+    ``configs`` gets every per-configuration check, with no other file of
+    the copy touched, and passes them."""
+    shutil.copytree(spec.BENCH_DIR, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    base = "mbv2-fp32-chain3-int8"
+    with open(spec.config_path(base)) as f:
+        cfg = json.load(f)
+    lacks = spec.load_checks(base)["program_lacks"]
+    cfg.update(name=PROBE, in_shape=[3, 96, 96],
+               layers=[layer for i, layer in enumerate(cfg["layers"])
+                       if i not in lacks])
+    (tmp_path / "bench" / "configs" / f"{PROBE}.json").write_text(
+        json.dumps(cfg))
+    checks = dict(spec.load_checks(base), program_lacks=[],
+                  control_cuts=[5, 15],
+                  totals={"flops": 1.1367e8, "flops_rel": 1e-3,
+                          "cuts": [5, 15], "conv_calls": 52})
+    (tmp_path / "bench" / "tests" / "data" / "configs" /
+     f"{PROBE}.json").write_text(json.dumps(checks))
+    bench = spec.load_benchmark()
+    bench["configs"].append(dict(bench["configs"][-1], name=PROBE,
+                                 file=f"bench/configs/{PROBE}.json"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [str(tmp_path), os.path.join(spec.ROOT, "src")]))
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "-k", PROBE, "bench/tests"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-2000:]
+    passed = re.findall(r"::(\w+)\[[^\]]*" + PROBE + r"[^\]]*\] PASSED",
+                        proc.stdout)
+    assert collections.Counter(passed) == PER_CONFIG_TESTS, proc.stdout[-4000:]

@@ -42,6 +42,12 @@ def test_kernel_time(trace):
     assert [e.dur_ns for e in quant] == [1000.0]
 
 
+def test_kernel_events_of_the_whole_trace(trace):
+    """``whole`` keeps the calls that lie outside the host spans' window."""
+    quant = tracefile.kernel_events(trace, r"_quantize\.\d+", whole=True)
+    assert [e.start_ns for e in quant] == [1_025_000.0, 1_030_000.0]
+
+
 def test_breakdown(trace):
     assert tracefile.top_ops(trace) == [
         ["jit__conv2d/" + CONV_OP, 7e-06], ["jit_stack/fusion.1", 2e-06],
@@ -82,6 +88,22 @@ def test_conv_roofline_is_not_read_when_calls_do_not_add_up(trace):
         is None
     assert spec.load_reader("conv_roofline.lat")(
         dict(_ctx(trace), trace=None)) is None
+
+
+def test_conv_roofline_counts_a_call_placed_before_the_window():
+    """The device's timeline can sit a millisecond early against the
+    host's, so the first step's first call can lie before the first host
+    span: it still counts, as one of the traced steps' calls."""
+    conv = "%_conv2d.1 = f32[1] custom-call()"
+    early = tracefile.Trace(
+        devices=[[tracefile.Event(conv, 0.5e6, 2000.0, {}),
+                  tracefile.Event(conv, 1.2e6, 3000.0, {})]],
+        spans=[tracefile.Event("step", 1e6, 1e6, {})])
+    ctx = _ctx(early)
+    launch = ctx["conv_launches"][0]
+    least = max(launch["flops"] / 197e12, launch["bytes"] / 819e9)
+    assert spec.load_reader("conv_roofline.lat")(ctx) == pytest.approx(
+        100 * 2 * least / 5e-6)
 
 
 NEW_SPAN_TRACE = """

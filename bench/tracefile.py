@@ -144,14 +144,19 @@ def op_name(e: Event) -> str:
     return str(name).strip().lstrip("%")
 
 
-def kernel_events(trace: Trace, pattern: str) -> list[Event]:
+def kernel_events(trace: Trace, pattern: str,
+                  whole: bool = False) -> list[Event]:
     """Device ops in the window whose instruction name (``op_name``)
-    matches ``pattern``, a regular expression, in full."""
+    matches ``pattern``, a regular expression, in full.  With ``whole``,
+    every such op the trace holds: the device's timeline is placed on the
+    host's with an error of a millisecond or so, so an op of the window's
+    first step can fall before its first host span."""
     w = window(trace)
     if w is None:
         return []
     rx = re.compile(pattern)
-    return [e for ops in trace.devices for e in in_window(ops, *w)
+    return [e for ops in trace.devices
+            for e in (ops if whole else in_window(ops, *w))
             if rx.fullmatch(op_name(e))]
 
 
