@@ -30,6 +30,7 @@ from __future__ import annotations
 import dataclasses
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,7 +54,8 @@ from repro.runtime.tier_faults import (FaultyTier, TierCrash, TierError,
                                        TierShed)
 from repro.runtime.transfer import (RetryPolicy, TransferFailed,
                                     send_with_retry)
-from repro.runtime.wire import decode_boundary, encode_boundary
+from repro.runtime.wire import (decode_boundary, encode_boundary,
+                                finish_boundary, queue_boundary)
 
 
 class SplitUnrecoverable(RuntimeError):
@@ -723,6 +725,8 @@ class ChainRuntime:
         self.hop_goodput_bytes = [0] * n_hops
         self.hop_raw_bytes = [0] * n_hops
         self.hop_merges = [0] * n_hops
+        self.n_ahead = 0            # stage-0 dispatches queued ahead
+        self.n_ahead_used = 0       # of those, used by their microbatch
 
     # -- stages --------------------------------------------------------
     @ev.spanned(ev.SPAN_CHAIN_STAGE)
@@ -730,6 +734,18 @@ class ChainRuntime:
         return cnn_lib.apply_cnn(self.layers, self.params, x, start=start,
                                  stop=stop, backend=self.backend,
                                  dtype=self.dtype)
+
+    def _run_ahead(self, x, stop: int):
+        """Stage 0 of the next microbatch, queued on the device while the
+        host still has this microbatch's last hop to do.  Returns the
+        ``((start, stop), result)`` that ``infer`` uses in place of that
+        microbatch's own stage 0 when the range still matches.  It touches
+        no virtual clock, RNG, link or event log.  Its ``chain.stage`` span
+        nests in the hop's ``wire.encode``: the chip's idle time under it
+        goes to the innermost span, the stage, and ``wire.encode``'s self
+        time does not hold it."""
+        self.n_ahead += 1
+        return (0, stop), self._run(x, 0, stop)
 
     def _stage_seconds(self, tier_id: int, start: int, stop: int) -> float:
         """Whole-batch compute seconds for layers [start, stop) on a tier
@@ -854,7 +870,15 @@ class ChainRuntime:
         earlier than the previous request's makespan overlaps it --
         the serving engine's cross-request pipelining; stages still
         start no earlier than both the arrival and the tier's previous
-        claim, so the schedule stays FIFO-valid per resource."""
+        claim, so the schedule stays FIFO-valid per resource.
+
+        Stage 0 of microbatch m+1 depends on the input alone, so it is
+        queued on the device (``_run_ahead``) once m's last hop has queued
+        its own device work and before the host waits on that hop.  m+1
+        uses it if its stage 0 still runs the same range and passes its
+        vetting; a merge, re-pick, failover or fallback that moved the
+        first cut, or a rejected stage, discards it.  The host's order --
+        fault draws, events, clock claims, bytes -- is unchanged."""
         self.n_requests += 1
         mark = len(self.log)
         self._maybe_proactive_repick()
@@ -888,8 +912,12 @@ class ChainRuntime:
         outs = []
         mb_finish: list[float] = []
         finish = t0
+        x_next = ahead = None
         for m in range(M):
-            x_m = x[slices[m][0]:slices[m][1]]
+            # the slice, and the stage 0 that microbatch m-1 queued ahead
+            x_m = x[slices[m][0]:slices[m][1]] if x_next is None \
+                else x_next
+            held, x_next, ahead = ahead, None, None
             cur = x_m
             layer = 0
             s = 0
@@ -937,6 +965,7 @@ class ChainRuntime:
                     # Tier-failure ladder: upstream stage merge ->
                     # cached-front re-pick (transient failures only) ->
                     # standby failover -> on-device fallback -> give up.
+                    held = None
                     tier_free[tier_id] = t_fail
                     ready = t_fail
                     persistent = rejected
@@ -1019,7 +1048,12 @@ class ChainRuntime:
                         f"failover and on-device fallback all "
                         f"unavailable") from tier_fail
                 if stop > layer:
-                    cur = self._run(cur, layer, stop)
+                    if held is not None and held[0] == (layer, stop):
+                        cur = held[1]
+                        self.n_ahead_used += 1
+                    else:
+                        cur = self._run(cur, layer, stop)
+                held = None
                 tier_free[tier_id] = t_start + dt
                 ready = t_start + dt
                 layer = stop
@@ -1027,7 +1061,16 @@ class ChainRuntime:
                     break
                 hop_id = hops[s]
                 w = self.wire_dtypes[hop_id]
-                data, meta = encode_boundary(cur, w, backend=self.backend)
+                with jax.profiler.TraceAnnotation(ev.SPAN_WIRE_ENCODE):
+                    queued = queue_boundary(cur, w, backend=self.backend)
+                    if x_next is None and m + 1 < M \
+                            and s == len(hops) - 1 and edges[1] > 0:
+                        # m's last hop: m+1's stage 0 goes into the device
+                        # queue behind this hop's cast or quantize and
+                        # copies, before the host waits for them
+                        x_next = x[slices[m + 1][0]:slices[m + 1][1]]
+                        ahead = self._run_ahead(x_next, edges[1])
+                    data, meta = finish_boundary(*queued)
                 tx = max(link_free[hop_id], ready)
                 if w != self._storage:
                     self.log.emit(ev.WIRE_ENCODE, tx,
@@ -1154,6 +1197,8 @@ class ChainRuntime:
             "active_cuts": list(self.plan.cuts),
             "active_tiers": [t.name for t in self.hw.tiers],
             "microbatches": self.microbatches,
+            "stage_ahead": self.n_ahead,
+            "stage_ahead_used": self.n_ahead_used,
             "tiers": None if self.tier_faults is None else
                 [ft.counters() for ft in self.tier_faults],
             "breakers": None if self.breakers is None else

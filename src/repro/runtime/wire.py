@@ -48,15 +48,48 @@ class BoundaryMeta:
     raw_bytes: int = 0           # storage-dtype serialized size (stats)
 
 
-def _sync(*arrays) -> None:
-    """Start each array's copy to the host, then wait for the device work
-    that makes them inside the span ``wire.sync``: a trace then tells the
-    stage's device time apart from the codec's host time.  The copies are
-    queued first so that the wait adds no round trip to the hop."""
+def queue_boundary(arr, wire: str, *, backend: str | None = None
+                   ) -> tuple[tuple, BoundaryMeta]:
+    """The device half of ``encode_boundary``: queue the cast or the int8
+    quantize, then each result's copy to the host, and wait for none of
+    it.  Returns ``(arrays, meta)`` for ``finish_boundary``.  A caller can
+    queue more device work between the two halves without putting it
+    ahead of what the hop's wait covers; it opens the ``wire.encode`` span
+    around both halves itself, as ``encode_boundary`` does."""
+    storage = np.dtype(arr.dtype)
+    shape = tuple(int(d) for d in arr.shape)
+    raw_bytes = int(arr.size) * storage.itemsize
+    if wire == "int8":
+        axis = default_channel_axis(arr.ndim)
+        arrays = quantize_boundary(arr, axis, backend=backend)
+        meta = BoundaryMeta(wire=wire, storage=storage, shape=shape,
+                            axis=axis, framed=INT8_FRAME_LABELS,
+                            raw_bytes=raw_bytes)
+    else:
+        jdt = policy_jnp_dtype(wire)
+        arrays = (arr if arr.dtype == jdt else arr.astype(jdt),)
+        meta = BoundaryMeta(wire=wire, storage=storage, shape=shape,
+                            raw_bytes=raw_bytes)
     for a in arrays:
         a.copy_to_host_async()
+    return tuple(arrays), meta
+
+
+def finish_boundary(arrays: tuple, meta: BoundaryMeta
+                    ) -> tuple[bytes, BoundaryMeta]:
+    """The host half of ``encode_boundary``, for what ``queue_boundary``
+    queued: wait for the device work that makes ``arrays`` inside the span
+    ``wire.sync`` (a trace then tells the stage's device time apart from
+    the codec's host time), then make the payload bytes.  The copies were
+    queued first, so the wait adds no round trip to the hop."""
     with jax.profiler.TraceAnnotation(ev.SPAN_WIRE_SYNC):
         jax.block_until_ready(arrays)
+    if meta.framed:
+        q, scales = arrays
+        q_host = np.ascontiguousarray(np.asarray(q))
+        s_host = np.ascontiguousarray(np.asarray(scales, dtype=np.float32))
+        return pack_frames(s_host.tobytes(), q_host.tobytes()), meta
+    return np.ascontiguousarray(np.asarray(arrays[0])).tobytes(), meta
 
 
 @ev.spanned(ev.SPAN_WIRE_ENCODE)
@@ -68,25 +101,7 @@ def encode_boundary(arr, wire: str, *, backend: str | None = None
     ``follow`` with ``core.dtype_policy.resolve_wire_dtype`` first.  When
     the wire format equals the array's dtype the payload is bit-identical
     to ``np.asarray(arr).tobytes()`` (the legacy raw path)."""
-    storage = np.dtype(arr.dtype)
-    shape = tuple(int(d) for d in arr.shape)
-    raw_bytes = int(arr.size) * storage.itemsize
-    if wire == "int8":
-        axis = default_channel_axis(arr.ndim)
-        q, scales = quantize_boundary(arr, axis, backend=backend)
-        _sync(q, scales)
-        q_host = np.ascontiguousarray(np.asarray(q))
-        s_host = np.ascontiguousarray(np.asarray(scales, dtype=np.float32))
-        payload = pack_frames(s_host.tobytes(), q_host.tobytes())
-        return payload, BoundaryMeta(
-            wire=wire, storage=storage, shape=shape, axis=axis,
-            framed=INT8_FRAME_LABELS, raw_bytes=raw_bytes)
-    jdt = policy_jnp_dtype(wire)
-    sent = arr if arr.dtype == jdt else arr.astype(jdt)
-    _sync(sent)
-    host = np.ascontiguousarray(np.asarray(sent))
-    return host.tobytes(), BoundaryMeta(
-        wire=wire, storage=storage, shape=shape, raw_bytes=raw_bytes)
+    return finish_boundary(*queue_boundary(arr, wire, backend=backend))
 
 
 @ev.spanned(ev.SPAN_WIRE_DECODE)

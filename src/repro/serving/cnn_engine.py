@@ -500,6 +500,10 @@ class CnnServingEngine:
             # stage programs (``cnn.walk_cache_stats``, process-wide)
             "walk_traces": walk["traces"],
             "walk_hits": walk["hits"],
+            # stage-0 dispatches queued ahead of a hop's wait, and of
+            # those the ones their microbatch used (``ChainRuntime.infer``)
+            "stage_ahead": sum(rt.n_ahead for rt in runtimes),
+            "stage_ahead_used": sum(rt.n_ahead_used for rt in runtimes),
             "tiers": None if self.tier_faults is None else
                 [ft.counters() for ft in self.tier_faults],
             "breakers": None if self.breakers is None else

@@ -177,6 +177,87 @@ def test_permanent_outage_without_merge_is_unrecoverable(tiny):
 
 
 # ---------------------------------------------------------------------------
+# Stage 0 of the next microbatch, queued ahead of this one's last hop
+# ---------------------------------------------------------------------------
+def test_stage_ahead_clean_bit_identical(tiny):
+    """Each microbatch's logits equal ``apply_split`` of its slice alone;
+    microbatches 1-3 use the stage 0 queued ahead for them, and M=1
+    queues none."""
+    params, x = tiny
+    prof, hw, plan = _chain_plan(3)
+    assert plan.cuts[0] > 0
+    rt = ChainRuntime(TINY_LAYERS, params, plan, prof, hw, microbatches=4)
+    for n in (1, 2):
+        r = rt.infer(x)
+        assert r.cuts == plan.cuts and not r.degraded
+        for i, (a, b) in enumerate(microbatch_slices(x.shape[0], 4)):
+            want, _ = cnn_lib.apply_split(TINY_LAYERS, params, x[a:b],
+                                          plan.cuts[0])
+            np.testing.assert_array_equal(np.asarray(r.logits[a:b]),
+                                          np.asarray(want), err_msg=str(i))
+        s = rt.stats()
+        assert s["stage_ahead"] == s["stage_ahead_used"] == 3 * n
+    one = ChainRuntime(TINY_LAYERS, params, plan, prof, hw, microbatches=1)
+    one.infer(x)
+    assert one.stats()["stage_ahead"] == one.stats()["stage_ahead_used"] \
+        == 0
+
+
+def _lossy_run(tiny, outage, merge_fallback):
+    """Two M=4 requests over a 3-tier chain whose hops drop and corrupt
+    (seeded), hop 1 also down for ``outage``."""
+    params, x = tiny
+    prof, hw, plan = _chain_plan(3)
+    clock = VirtualClock()
+    specs = [FaultSpec(drop_rate=0.2, corrupt_rate=0.2),
+             FaultSpec(drop_rate=0.2, corrupt_rate=0.2, outages=(outage,))]
+    links = [FaultyLink(link.bandwidth, clock=clock, seed=7 + k, faults=sp)
+             for k, (link, sp) in enumerate(zip(hw.links, specs))]
+    rt = ChainRuntime(TINY_LAYERS, params, plan, prof, hw, links=links,
+                      microbatches=4, merge_fallback=merge_fallback,
+                      policy=RetryPolicy(max_attempts=3, timeout_s=0.01,
+                                         backoff_base_s=0.005))
+    return rt, [rt.infer(x) for _ in range(2)]
+
+
+@pytest.mark.parametrize("case", ["merge", "repick"])
+def test_stage_ahead_changes_nothing_under_faults(tiny, monkeypatch, case):
+    """Lossy hops plus an outage on hop 1 from the third microbatch on:
+    permanent, so hop 1 merges, or a window with merges off, so the chain
+    re-picks onto another first cut.  Logits, cuts, events, virtual times
+    and every counter equal those of the same run with nothing queued
+    ahead; a stage 0 queued before the first cut moved is discarded."""
+    outage, merge = {"merge": ((0.004, 1e9), None),
+                     "repick": ((0.004, 0.045), False)}[case]
+    rt, got = _lossy_run(tiny, outage, merge)
+    with monkeypatch.context() as mp:
+        mp.setattr(ChainRuntime, "_run_ahead", lambda self, x, stop: None)
+        base_rt, want = _lossy_run(tiny, outage, merge)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g.logits),
+                                      np.asarray(w.logits))
+        assert (g.cuts, g.events, g.chain_elapsed_s,
+                g.microbatch_finish_s, g.merged_hops) == \
+            (w.cuts, w.events, w.chain_elapsed_s, w.microbatch_finish_s,
+             w.merged_hops)
+    assert [link.counters() for link in rt.links] == \
+        [link.counters() for link in base_rt.links]
+    s, base = rt.stats(), base_rt.stats()
+    ahead, used = s.pop("stage_ahead"), s.pop("stage_ahead_used")
+    assert (base.pop("stage_ahead"), base.pop("stage_ahead_used")) == (0, 0)
+    assert s == base
+    assert ahead == 6                       # 3 a request
+    if case == "merge":
+        assert s["merges"] == 2 and s["repicks"] == 0
+        assert got[0].cuts == got[1].cuts == (3,)
+        assert used == ahead                # the first cut never moved
+    else:
+        assert s["merges"] == 0 and s["repicks"] == 1
+        assert got[0].planned_cuts == (3, 6) and got[0].cuts == (1, 6)
+        assert used == ahead - 1            # queued for cut 3, ran cut 1
+
+
+# ---------------------------------------------------------------------------
 # Observability: per-hop counters in both runtimes
 # ---------------------------------------------------------------------------
 def test_chain_stats_per_hop(tiny):

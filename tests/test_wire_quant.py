@@ -34,6 +34,7 @@ from repro.runtime import (ChainRuntime, FaultSpec, FaultyLink, FrameError,
                            SplitRuntime, TransferFailed, decode_boundary,
                            encode_boundary, events, pack_frames,
                            send_with_retry, unpack_frames)
+from repro.runtime.wire import finish_boundary, queue_boundary
 
 TINY_LAYERS = [conv(8, 3, 1, 1), relu(), maxpool(2, 2),
                conv(16, 3, 1, 1), relu(), avgpool(2), linear(10)]
@@ -205,6 +206,18 @@ def test_encode_decode_matches_boundary_roundtrip(wire):
             + INT8_FRAME_OVERHEAD_BYTES
     else:
         assert meta.framed is None
+
+
+@pytest.mark.parametrize("wire", ["fp32", "bf16", "int8"])
+def test_boundary_halves_match_encode(wire):
+    """``queue_boundary`` then ``finish_boundary``, with other device work
+    queued between them, give ``encode_boundary``'s bytes and meta."""
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(size=(2, 6, 5, 5)), jnp.float32)
+    queued = queue_boundary(x, wire)
+    behind = jnp.tanh(x) * 2.0
+    assert finish_boundary(*queued) == encode_boundary(x, wire)
+    behind.block_until_ready()
 
 
 def test_raw_wire_path_is_legacy_bytes():
